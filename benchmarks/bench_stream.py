@@ -76,8 +76,8 @@ window path pays the extra per-sample eviction downdate).
 
 Fifth table (ISSUE 6, ``--sharded``): served-samples/sec vs slot-mesh
 device count (1/2/4/8) at Nx in {8, 16} x S in {64, 256}, window=1.  The
-sharded episodes are bitwise the single-device episodes, so the columns
-measure pure serving-harness scaling.  Tracked in BENCH_stream_sharded.json
+sharded episodes serve the single-device episodes (``runtime.parity``), so
+the columns measure pure serving-harness scaling.  Tracked in BENCH_stream_sharded.json
 (written by ``benchmarks/run.py --only stream_sharded``).  Columns where
 the mesh has more devices than the host has physical cores
 (``os.cpu_count()``) are flagged ``dN_oversubscribed`` and report an
@@ -109,7 +109,6 @@ from __future__ import annotations
 import argparse
 import json
 import os
-import subprocess
 import sys
 import time
 from typing import Dict, List, Tuple
@@ -566,7 +565,7 @@ def _bench_sharded_case(n_streams: int, n_samples: int, t_len: int,
     """One device-count scaling cell: the identical episode (same streams,
     refresh_mode='incremental', window=1 sample-by-sample serving) served
     by ``StreamServer(devices=n)`` for each mesh size.  Sharded episodes
-    are bitwise the devices=1 episode (tests/test_stream_sharded.py), so
+    serve the devices=1 episode (tests/test_stream_sharded.py), so
     every column serves exactly the same computation - the table measures
     the scaling of the serving harness alone.
 
@@ -624,10 +623,12 @@ def _bench_sharded_case(n_streams: int, n_samples: int, t_len: int,
 
 
 def run_sharded(full: bool = False, smoke: bool = False) -> List[Dict]:
-    """The device-count scaling table.  Needs >= 8 XLA devices; when the
-    process has fewer (the common single-device CLI run), it re-execs
-    itself in a subprocess with ``--xla_force_host_platform_device_count=8``
-    (the flag must be set before jax initializes) and parses the rows back.
+    """The device-count scaling table over the devices this process has:
+    columns for mesh sizes beyond ``jax.device_count()`` are left out.  On
+    a CPU host, give the process virtual devices before it starts
+    (``XLA_FLAGS=--xla_force_host_platform_device_count=8``); nothing here
+    starts a second process, which on a chip host could not reach a chip
+    this one already holds.
     """
     # sharded cases (n_streams, n_samples, t_len, n_nodes): Nx in {8, 16} x
     # S in {64, 256} per the tracked BENCH_stream_sharded.json contract
@@ -638,27 +639,6 @@ def run_sharded(full: bool = False, smoke: bool = False) -> List[Dict]:
         cases = [(64, 16, 24, 8), (64, 16, 24, 16),
                  (256, 16, 24, 8), (256, 16, 24, 16)]
         counts = SHARDED_DEVICE_COUNTS
-    if jax.device_count() < max(counts):
-        env = dict(os.environ)
-        env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
-                            + f" --xla_force_host_platform_device_count="
-                              f"{max(counts)}").strip()
-        env["PYTHONPATH"] = os.pathsep.join(
-            p for p in ("src", env.get("PYTHONPATH", "")) if p
-        )
-        mode = ["--smoke"] if smoke else (["--full"] if full else [])
-        out = subprocess.run(
-            [sys.executable, os.path.abspath(__file__),
-             "--sharded", "--json", *mode],
-            capture_output=True, text=True, env=env, timeout=3600,
-            cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-        )
-        if out.returncode != 0:
-            raise RuntimeError(
-                f"sharded bench subprocess failed:\n{out.stderr[-3000:]}"
-            )
-        return [json.loads(line) for line in out.stdout.splitlines()
-                if line.startswith("{")]
     return [_bench_sharded_case(*c, device_counts=counts) for c in cases]
 
 
@@ -995,8 +975,9 @@ def main() -> None:
     ap.add_argument("--smoke", action="store_true",
                     help="one tiny case (CI lane)")
     ap.add_argument("--sharded", action="store_true",
-                    help="the device-count scaling table only (forces 8 "
-                         "virtual devices in a subprocess when needed)")
+                    help="the device-count scaling table only, over the "
+                         "devices this process has (on CPU set XLA_FLAGS="
+                         "--xla_force_host_platform_device_count=8 first)")
     ap.add_argument("--quant", action="store_true",
                     help="the int8 fast-path + step-blocking table only")
     ap.add_argument("--planner", action="store_true",
@@ -1030,4 +1011,7 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

@@ -50,8 +50,8 @@ def main() -> None:
         "train_fused": lambda: bench_backprop.run_train_fused(args.full),
         "roofline": lambda: roofline.summary_csv(),
     }
-    # opt-in only: the sharded sweep re-execs under 8 forced XLA devices,
-    # which the default suite run shouldn't silently do
+    # opt-in only: the sharded sweep needs a process started with several
+    # devices (on CPU: XLA_FLAGS=--xla_force_host_platform_device_count=8)
     default_suites = [s for s in suites if s != "stream_sharded"]
     selected = (args.only.split(",") if args.only else default_suites)
 
@@ -166,4 +166,7 @@ def _write_bench_json(name, rows) -> None:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

@@ -38,11 +38,13 @@ without being told lambda, the capacity, or that a drift exists.
 (p, q, beta) population re-evaluated on recent retained windows, with
 winners hot-swapped into live slots at refresh boundaries.
 
-Sharded serving (``--devices N``): shard the server's slot axis over N
-devices (PR 6; ``--max-streams`` is rounded up to a multiple of N).  On a
-CPU-only host the flag also forces the XLA host-device split, so
-``--devices 8`` works out of the box - the episode is bitwise the
-single-device one; only the placement changes.
+Sharded serving (``--devices N``): shard the server's slot axis over N of
+the process's devices (PR 6; ``--max-streams`` is rounded up to a multiple
+of N); asking for more devices than exist fails.  On a CPU-only host, give
+the process virtual devices before it starts
+(``XLA_FLAGS=--xla_force_host_platform_device_count=8``).  The episode is
+the single-device one (``repro.runtime.parity``); only the placement
+changes.
 
 Quantized serving (``--quantize int8``, PR 7): armed slots answer from the
 int8 fused fast path (coded readout + reservoir state, integer compute,
@@ -57,28 +59,6 @@ compose with ``--devices``:
         --quantize int8 --devices 8
 """
 import argparse
-import os
-import sys
-
-
-def _sniff_devices() -> int:
-    """--devices before jax initializes: device counts lock on first jax
-    import, so the CPU host split must be forced from argv, pre-import."""
-    argv = sys.argv
-    for k, a in enumerate(argv):
-        if a == "--devices" and k + 1 < len(argv):
-            return int(argv[k + 1])
-        if a.startswith("--devices="):
-            return int(a.split("=", 1)[1])
-    return 1
-
-
-_DEVICES = _sniff_devices()
-if _DEVICES > 1:
-    os.environ["XLA_FLAGS"] = (
-        os.environ.get("XLA_FLAGS", "")
-        + f" --xla_force_host_platform_device_count={_DEVICES}"
-    ).strip()
 
 import numpy as np
 import jax
@@ -294,11 +274,10 @@ def main():
                          "synchronous; the served episode is bit-identical "
                          "at every depth)")
     ap.add_argument("--devices", type=int, default=1, metavar="N",
-                    help="shard the server's slot axis over N devices "
-                         "(PR 6; rounds --max-streams up to a multiple of "
-                         "N; forces the XLA host-device split on CPU so "
-                         "N > physical devices works; the episode is "
-                         "bitwise the single-device one)")
+                    help="shard the server's slot axis over N of the "
+                         "process's devices (PR 6; rounds --max-streams up "
+                         "to a multiple of N; on CPU set XLA_FLAGS="
+                         "--xla_force_host_platform_device_count=N first)")
     ap.add_argument("--quantize", choices=("none", "int8"), default="none",
                     help="serve armed slots from the int8 fused fast path "
                          "(PR 7): coded readout + reservoir state, integer "
@@ -409,4 +388,7 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

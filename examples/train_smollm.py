@@ -14,6 +14,9 @@ import sys
 from repro.launch.train import main
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     preset = [
         "--arch", "smollm-135m",
         "--steps", "300",

@@ -23,7 +23,7 @@ import numpy as np
 
 from repro.core import dprr as dprr_mod
 from repro.core import reservoir as res_mod
-from repro.core.types import Array, DFRConfig, DFRParams
+from repro.core.types import DOT_PRECISION, Array, DFRConfig, DFRParams
 
 
 class ForwardAux(NamedTuple):
@@ -65,7 +65,7 @@ def forward(
     batched = j_seq.ndim == 3
     x = res_mod.run_reservoir(params.p, params.q, j_seq, f=f, lengths=lengths)
     r = dprr_mod.compute_dprr(x, lengths=lengths)
-    logits = r @ params.W.T + params.b
+    logits = jnp.matmul(r, params.W.T, precision=DOT_PRECISION) + params.b
     probs = jax.nn.softmax(logits, axis=-1)
     # gather x(T), x(T-1), j(T) (with variable lengths, T = lengths per row)
     if lengths is None:
@@ -198,7 +198,7 @@ def truncated_loss_from_aux(
     delta_sum = delta[..., :, n_nodes]
     r = sg(aux.r) + jnp.concatenate([delta_outer, delta_sum], axis=-1)
 
-    logits = r @ params.W.T + params.b
+    logits = jnp.matmul(r, params.W.T, precision=DOT_PRECISION) + params.b
     return jnp.sum(loss_fn(logits, onehot))
 
 
@@ -351,7 +351,7 @@ def forward_fused(
     r, x_last, x_prev, j_last = _fused_features(
         spec, params.p, params.q, j_seq, lengths
     )
-    logits = r @ params.W.T + params.b
+    logits = jnp.matmul(r, params.W.T, precision=DOT_PRECISION) + params.b
     probs = jax.nn.softmax(logits, axis=-1)
     return ForwardAux(logits, probs, r, x_last, x_prev, j_last)
 

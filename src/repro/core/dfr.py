@@ -18,7 +18,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import backprop, dprr, masking, reservoir, ridge
-from repro.core.types import Array, DFRConfig, DFRParams, TimeSeriesBatch
+from repro.core.types import (DOT_PRECISION, Array, DFRConfig, DFRParams,
+                              TimeSeriesBatch)
 
 
 @partial(jax.jit, static_argnames=("cfg", "minibatch"))
@@ -76,7 +77,7 @@ class DFRModel:
 
     def logits(self, batch: TimeSeriesBatch, params: DFRParams) -> Array:
         r = self.features(batch, params)
-        return r @ params.W.T + params.b
+        return jnp.matmul(r, params.W.T, precision=DOT_PRECISION) + params.b
 
     def predict(self, batch: TimeSeriesBatch, params: DFRParams) -> Array:
         return jnp.argmax(self.logits(batch, params), axis=-1)

@@ -18,7 +18,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro.core.types import Array
+from repro.core.types import DOT_PRECISION, Array
 
 
 def shifted_states(x: Array) -> Array:
@@ -50,7 +50,7 @@ def compute_dprr(x: Array, lengths: Optional[Array] = None) -> Array:
     else:
         x1m = x
     # R[i, j] = sum_k x(k)_i x(k-1)_j   -> contraction over time on the MXU
-    outer = jnp.einsum("...ki,...kj->...ij", x1m, x0)
+    outer = jnp.einsum("...ki,...kj->...ij", x1m, x0, precision=DOT_PRECISION)
     sums = jnp.sum(x1m, axis=-2)  # (..., Nx)
     flat = outer.reshape(*outer.shape[:-2], n_nodes * n_nodes)
     return jnp.concatenate([flat, sums], axis=-1)

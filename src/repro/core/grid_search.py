@@ -25,7 +25,8 @@ import numpy as np
 
 from repro.core import backprop, dprr, masking, population, reservoir, ridge
 from repro.core.population import grid_points  # noqa: F401  (compat re-export)
-from repro.core.types import Array, DFRConfig, DFRParams, TimeSeriesBatch
+from repro.core.types import (DOT_PRECISION, Array, DFRConfig, DFRParams,
+                              TimeSeriesBatch)
 
 
 def _eval_pq(
@@ -49,16 +50,16 @@ def _eval_pq(
     r_test = feats(test)
     rt = dprr.r_tilde(r_train)
     onehot = jax.nn.one_hot(train.label, cfg.n_classes, dtype=cfg.dtype)
-    A = jnp.einsum("bc,bs->cs", onehot, rt)
-    B = jnp.einsum("bs,bt->st", rt, rt)
+    A = jnp.einsum("bc,bs->cs", onehot, rt, precision=DOT_PRECISION)
+    B = jnp.einsum("bs,bt->st", rt, rt, precision=DOT_PRECISION)
 
     accs, losses = [], []
     for beta in betas:
         Wt = ridge.ridge_cholesky_blocked(A, ridge.regularize(B, jnp.asarray(beta, B.dtype)))
         W, b = Wt[:, :-1], Wt[:, -1]
-        logits_test = r_test @ W.T + b
+        logits_test = jnp.matmul(r_test, W.T, precision=DOT_PRECISION) + b
         acc = jnp.mean((jnp.argmax(logits_test, -1) == test.label).astype(jnp.float32))
-        logits_train = r_train @ W.T + b
+        logits_train = jnp.matmul(r_train, W.T, precision=DOT_PRECISION) + b
         loss = jnp.mean(backprop.loss_from_logits(
             logits_train, jax.nn.one_hot(train.label, cfg.n_classes, dtype=cfg.dtype)))
         accs.append(acc)

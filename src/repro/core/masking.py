@@ -13,7 +13,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.core.types import Array
+from repro.core.types import DOT_PRECISION, Array
 
 
 def make_mask(
@@ -43,4 +43,4 @@ def apply_mask(mask: Array, u: Array) -> Array:
 
     u: (..., n_in)  ->  j: (..., Nx)
     """
-    return jnp.einsum("ni,...i->...n", mask, u)
+    return jnp.einsum("ni,...i->...n", mask, u, precision=DOT_PRECISION)

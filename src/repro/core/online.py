@@ -42,8 +42,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import backprop, candidates, dprr, masking, reservoir, ridge
-from repro.core.types import (Array, DFRConfig, DFRParams, QuantParams,
-                              RidgeState)
+from repro.core.types import (DOT_PRECISION, Array, DFRConfig, DFRParams,
+                              QuantParams, RidgeState)
 
 
 @jax.tree_util.register_pytree_node_class
@@ -296,7 +296,8 @@ def online_logits(
         state.params.p, state.params.q, j_seq, f=f, lengths=length
     )
     r = dprr.compute_dprr(x, lengths=length)
-    return r @ state.params.W.T + state.params.b
+    logits = jnp.matmul(r, state.params.W.T, precision=DOT_PRECISION)
+    return logits + state.params.b
 
 
 def online_infer(
@@ -385,7 +386,7 @@ def online_step(
         loss_fast=state.loss_fast,
         loss_slow=state.loss_slow,
     )
-    logits = r @ params.W.T + params.b
+    logits = jnp.matmul(r, params.W.T, precision=DOT_PRECISION) + params.b
     hits = (jnp.argmax(logits, -1) == label).astype(jnp.float32)
     if weight is not None:
         hits = hits * weight

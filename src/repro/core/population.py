@@ -66,6 +66,7 @@ from repro.core.candidates import (  # noqa: F401  (shared candidate machinery,
     init_population,
 )
 from repro.core.types import (
+    DOT_PRECISION,
     Array,
     DFRConfig,
     DFRParams,
@@ -145,7 +146,8 @@ def evaluate_population(
 
     if use_dual:
         # one factorization for the whole (beta, member) sweep
-        Kmat = jnp.einsum("kbs,kcs->kbc", rt_train, rt_train)   # (K, B, B)
+        Kmat = jnp.einsum("kbs,kcs->kbc", rt_train, rt_train,
+                          precision=DOT_PRECISION)       # (K, B, B)
         eye = jnp.eye(n_train, dtype=Kmat.dtype)
         G = Kmat[None] + betas[:, None, None, None] * eye        # (nb, K, B, B)
         C = jnp.linalg.cholesky(G.reshape(n_beta * k, n_train, n_train))
@@ -153,10 +155,13 @@ def evaluate_population(
         X = jax.vmap(
             lambda c, y: jax.scipy.linalg.cho_solve((c, True), y)
         )(C, y_b).reshape(n_beta, k, n_train, -1)
-        Wt_all = jnp.einsum("nkby,kbs->nkys", X, rt_train)       # (nb, K, Ny, s)
+        Wt_all = jnp.einsum("nkby,kbs->nkys", X, rt_train,
+                            precision=DOT_PRECISION)     # (nb, K, Ny, s)
     else:
-        A = jnp.einsum("by,kbs->kys", y_train, rt_train)
-        Bmat = jnp.einsum("kbs,kbt->kst", rt_train, rt_train)
+        A = jnp.einsum("by,kbs->kys", y_train, rt_train,
+                       precision=DOT_PRECISION)
+        Bmat = jnp.einsum("kbs,kbt->kst", rt_train, rt_train,
+                          precision=DOT_PRECISION)
         Wt_all = jnp.stack([
             ridge.ridge_solve_batched(
                 A, ridge.regularize(Bmat, beta.astype(Bmat.dtype)), ridge_method
@@ -164,7 +169,8 @@ def evaluate_population(
             for beta in betas
         ])
 
-    pred = jnp.einsum("kbs,nkys->nkby", rt_eval, Wt_all)         # (nb, K, Be, Ny)
+    pred = jnp.einsum("kbs,nkys->nkby", rt_eval, Wt_all,
+                      precision=DOT_PRECISION)           # (nb, K, Be, Ny)
     var = jnp.mean(jnp.square(y_eval - jnp.mean(y_eval))) + 1e-12
     err = pred - y_eval[None, None]
     nrmse = jnp.sqrt(jnp.mean(err * err, axis=(2, 3)) / var)     # (nb, K)
@@ -327,6 +333,7 @@ def train_population(
     q_range: Tuple[float, float] = Q_LOG_RANGE,
     mask: Optional[Array] = None,
     seed: int = 0,
+    fused: bool = True,
 ) -> PopulationResult:
     """Grid-seed K = divs^2 members, then ``rounds`` of (cull -> truncated-BP
     refine -> ridge re-evaluate), returning the best member ever evaluated.
@@ -335,7 +342,9 @@ def train_population(
     anneals as lr * 0.1^round (the paper's drop schedule compressed to round
     granularity); ``lr`` defaults to cfg.lr for classification and to a
     gentler 0.3 * cfg.lr for regression, where the unnormalized MSE gradient
-    runs much hotter than cross-entropy's.
+    runs much hotter than cross-entropy's.  ``fused`` picks the refinement
+    forward (``refine_population``): the fused kernel path, or the plain
+    scan it is checked against.
     """
     if task not in ("classification", "regression"):
         raise ValueError(f"unknown task: {task}")
@@ -379,6 +388,7 @@ def train_population(
         pop, losses = refine_population(
             cfg, mask, pop, train_u, train_len, y_train, lr_r, lr_r,
             steps=steps_per_round, minibatch=minibatch, loss=loss,
+            fused=fused,
         )
         ev = ev_pop(pop)
         cand = _best_member(pop, ev, cfg, select)

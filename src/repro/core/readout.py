@@ -21,7 +21,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import backprop, dprr, masking, reservoir, ridge
-from repro.core.types import Array, DFRConfig, DFRParams, RidgeState
+from repro.core.types import (DOT_PRECISION, Array, DFRConfig, DFRParams,
+                              RidgeState)
 
 
 def _maybe_psum(x, axis_names: Sequence[str]):
@@ -135,4 +136,5 @@ class DistributedDFRReadout:
 
     def predict(self, params: DFRParams, h: Array, lengths: Optional[Array] = None) -> Array:
         r = self.features(params, h, lengths)
-        return jnp.argmax(r @ params.W.T + params.b, axis=-1)
+        return jnp.argmax(jnp.matmul(r, params.W.T, precision=DOT_PRECISION) + params.b,
+                          axis=-1)

@@ -34,7 +34,7 @@ from typing import Callable, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.core.types import Array
+from repro.core.types import DOT_PRECISION, Array
 
 
 def ring_matrix(q: Array, n_nodes: int, dtype=jnp.float32) -> Array:
@@ -98,7 +98,7 @@ def reservoir_step(
         qpow = ring_powers(q, n_nodes, x_prev.dtype)
     a = p * f(j_k + x_prev)
     ring_in = x_prev[..., -1:]  # x(k-1)_{Nx}
-    return a @ L.T + ring_in * qpow
+    return jnp.matmul(a, L.T, precision=DOT_PRECISION) + ring_in * qpow
 
 
 @partial(jax.jit, static_argnames=("f", "with_lengths"))
@@ -165,7 +165,8 @@ def run_reservoir_legacy(
 
     def step(x_prev, j_k):
         a = (1.0 - decay) * f(x_prev, j_k)
-        x_k = a @ L.T if a.ndim > 1 else L @ a
+        x_k = (jnp.matmul(a, L.T, precision=DOT_PRECISION) if a.ndim > 1
+               else jnp.matmul(L, a, precision=DOT_PRECISION))
         x_k = x_k + x_prev[..., -1:] * qpow
         return x_k, x_k
 

@@ -59,7 +59,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.types import Array
+from repro.core.types import DOT_PRECISION, Array
 
 # ---------------------------------------------------------------------------
 # Packed 1-D triangular indexing (paper Eq. 41): P[i(i+1)/2 + j] = B[i][j],
@@ -143,7 +143,7 @@ def ridge_gaussian(A: Array, B: Array) -> Array:
         return B, Binv
 
     B, Binv = jax.lax.fori_loop(0, s, pivot, (B, Binv))
-    return A @ Binv
+    return jnp.matmul(A, Binv, precision=DOT_PRECISION)
 
 
 # ---------------------------------------------------------------------------
@@ -255,7 +255,8 @@ def trsm_packed_jax(Q: Array, P: Array, s: int) -> Array:
 
     def col_j(j, Q):
         rowj = _row_slice(Ppad, j, s)  # C[j, :j+1]
-        dot = Q @ jnp.where(ar < j, rowj, 0.0)  # (Ny,)
+        dot = jnp.matmul(Q, jnp.where(ar < j, rowj, 0.0),
+                         precision=DOT_PRECISION)  # (Ny,)
         val = (Q[:, j] - dot) / rowj[j]
         return Q.at[:, j].set(val)
 
@@ -275,7 +276,8 @@ def trsm_packed_rev_jax(Q: Array, P: Array, s: int) -> Array:
     def col_j(t, Q):
         j = s - 1 - t
         colj = P[col_starts + j] * (ar >= j)  # C[:, j] masked (k >= j)
-        dot = Q @ jnp.where(ar > j, colj, 0.0)
+        dot = jnp.matmul(Q, jnp.where(ar > j, colj, 0.0),
+                         precision=DOT_PRECISION)
         val = (Q[:, j] - dot) / colj[j]
         return Q.at[:, j].set(val)
 
@@ -323,7 +325,8 @@ def _trsm_right_lower_t(a: Array, L: Array) -> Array:
     n = L.shape[0]
 
     def body(j, x):
-        dot = x @ L[j, :]  # only cols < j of x are final; L[j, k>j] = 0
+        # only cols < j of x are final; L[j, k>j] = 0
+        dot = jnp.matmul(x, L[j, :], precision=DOT_PRECISION)
         # subtract the k == j self term that is not yet valid
         val = (a[:, j] - dot + x[:, j] * L[j, j]) / L[j, j]
         return x.at[:, j].set(val)
@@ -355,7 +358,7 @@ def cholesky_blocked_jnp(B: Array, block: int = 128) -> Array:
             Lpanel = _trsm_right_lower_t(panel, Lkk)
             a = jax.lax.dynamic_update_slice(a, Lpanel, (k0 + block, k0))
             trail = jax.lax.dynamic_slice(a, (k0 + block, k0 + block), (rest, rest))
-            trail = trail - Lpanel @ Lpanel.T
+            trail = trail - jnp.matmul(Lpanel, Lpanel.T, precision=DOT_PRECISION)
             a = jax.lax.dynamic_update_slice(a, trail, (k0 + block, k0 + block))
     return jnp.tril(a)[:s, :s]
 
@@ -809,11 +812,13 @@ def ridge_solve_from_factor_t_batched(
         cols = jax.lax.dynamic_slice(U, (0, 0, j0), (k, n, block))
         done = jnp.where(ridx[None, :, None] < j0, Y, 0.0)
         rhs = jax.lax.dynamic_slice(Y, (0, j0, 0), (k, block, ny))
-        rhs = rhs - jnp.einsum("ksb,ksn->kbn", cols, done)
+        rhs = rhs - jnp.einsum("ksb,ksn->kbn", cols, done,
+                               precision=DOT_PRECISION)
         Tb = jax.lax.dynamic_slice(cols, (0, j0, 0), (k, block, block))
         sol = jnp.zeros_like(rhs)
         for i in range(block):  # unrolled in-block forward substitution
-            v = (rhs[:, i, :] - jnp.einsum("kb,kbn->kn", Tb[:, :, i], sol))
+            v = (rhs[:, i, :] - jnp.einsum("kb,kbn->kn", Tb[:, :, i], sol,
+                                             precision=DOT_PRECISION))
             sol = sol.at[:, i, :].set(v / Tb[:, i, i][:, None])
         return jax.lax.dynamic_update_slice(Y, sol, (0, j0, 0))
 
@@ -827,11 +832,13 @@ def ridge_solve_from_factor_t_batched(
         rows = jax.lax.dynamic_slice(U, (0, j0, 0), (k, block, n))
         solved = jnp.where(ridx[None, :, None] >= j0 + block, Wt, 0.0)
         rhs = jax.lax.dynamic_slice(Y, (0, j0, 0), (k, block, ny))
-        rhs = rhs - jnp.einsum("kbs,ksn->kbn", rows, solved)
+        rhs = rhs - jnp.einsum("kbs,ksn->kbn", rows, solved,
+                               precision=DOT_PRECISION)
         Tb = jax.lax.dynamic_slice(rows, (0, 0, j0), (k, block, block))
         sol = jnp.zeros_like(rhs)
         for i in range(block - 1, -1, -1):  # unrolled backward substitution
-            v = (rhs[:, i, :] - jnp.einsum("kb,kbn->kn", Tb[:, i, :], sol))
+            v = (rhs[:, i, :] - jnp.einsum("kb,kbn->kn", Tb[:, i, :], sol,
+                                             precision=DOT_PRECISION))
             sol = sol.at[:, i, :].set(v / Tb[:, i, i][:, None])
         return jax.lax.dynamic_update_slice(Wt, sol, (0, j0, 0))
 
@@ -849,8 +856,8 @@ def accumulate_ab(A: Array, B: Array, r_tilde: Array, onehot: Array) -> Tuple[Ar
 
     r_tilde: (batch, s), onehot: (batch, Ny).
     """
-    A = A + jnp.einsum("bc,bs->cs", onehot, r_tilde)
-    B = B + jnp.einsum("bs,bt->st", r_tilde, r_tilde)
+    A = A + jnp.einsum("bc,bs->cs", onehot, r_tilde, precision=DOT_PRECISION)
+    B = B + jnp.einsum("bs,bt->st", r_tilde, r_tilde, precision=DOT_PRECISION)
     return A, B
 
 

@@ -23,6 +23,13 @@ import jax.numpy as jnp
 
 Array = jax.Array
 
+#: Matmul precision of the DFR's float32 math: masking, reservoir, DPRR
+#: features, readouts, the ridge statistics (A, B) and the solves against
+#: them.  On the TPU an f32 dot at default precision rounds its operands to
+#: bf16, and the ridge solve over s = Nx^2 + Nx + 1 features turns that
+#: rounding into lost accuracy.  A CPU computes f32 dots in f32 either way.
+DOT_PRECISION = jax.lax.Precision.HIGHEST
+
 
 # ---------------------------------------------------------------------------
 # Nonlinearities f for the modular DFR block.  The paper's evaluation uses

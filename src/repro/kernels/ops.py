@@ -73,6 +73,19 @@ def _pad_to(x: jax.Array, axis: int, mult: int) -> jax.Array:
     return jnp.pad(x, widths)
 
 
+def _pad_stream_batch(j_seq: jax.Array, lengths: jax.Array, n_pad: int,
+                      chunk_t: int):
+    """Pad (B, T, Nx) inputs to the fused streaming kernels' tiles: nodes
+    to n_pad, time to chunk_t, and B above 8 to a multiple of 8
+    (``streaming.batch_block``).  Pad rows get length 0, so they run
+    frozen and their logits are sliced off."""
+    jp = _pad_to(_pad_to(j_seq, 2, n_pad), 1, chunk_t)
+    lens = lengths.astype(jnp.int32)
+    if jp.shape[0] > 8:
+        jp, lens = _pad_to(jp, 0, 8), _pad_to(lens, 0, 8)
+    return jp, lens
+
+
 def _ring_padded(q: jax.Array, nx: int, n_pad: int):
     """Ring-padded (L, qpow) for the reservoir/streaming kernels: zero-pad
     to n_pad and mirror the true last node into the last padded lane so the
@@ -269,7 +282,7 @@ def streaming_logits(
     ny = W.shape[0]
     n_pad = max(128, -(-nx // 128) * 128)
     ny_pad = max(8, -(-ny // 8) * 8)
-    jp = _pad_to(_pad_to(j_seq, 2, n_pad), 1, chunk_t)
+    jp, lens = _pad_stream_batch(j_seq, lengths, n_pad, chunk_t)
     Lp, qp = _ring_padded(q, nx, n_pad)
     # readout tile w3 in the accumulator's (i, j) layout: dot-product block
     # at [:nx, :nx], sum block down the ones column j = nx
@@ -279,10 +292,10 @@ def streaming_logits(
     w3 = w3.at[:ny, :nx, :nx].set(Wblk)
     w3 = w3.at[:ny, :nx, nx].set(Wsum)
     out = streaming_step_pallas(
-        jp, Lp, qp, lengths.astype(jnp.int32), p, q, w3, nx,
+        jp, Lp, qp, lens, p, q, w3, nx,
         f=f, chunk_t=chunk_t, interpret=(backend == "interpret"),
     )
-    return out[:, :ny] + b
+    return out[:bsz, :ny] + b
 
 
 @functools.partial(
@@ -361,7 +374,8 @@ def streaming_logits_q8(
     ny = Wq.shape[0]
     n_pad = max(128, -(-nx // 128) * 128)
     ny_pad = max(8, -(-ny // 8) * 8)
-    jp = _pad_to(_pad_to(j_seq.astype(jnp.float32), 2, n_pad), 1, chunk_t)
+    jp, lens = _pad_stream_batch(j_seq.astype(jnp.float32), lengths, n_pad,
+                                 chunk_t)
     Lp, qp = _ring_padded(q, nx, n_pad)
     sL = symmetric_scale(jnp.max(jnp.abs(Lp)))
     Lq8 = quantize_symmetric(Lp, sL)
@@ -376,15 +390,13 @@ def streaming_logits_q8(
     w3q = w3q.at[:ny, :nx, nx].set(Wsum)
     scales = jnp.stack([p.astype(jnp.float32), sx, sL, sw])
     if backend == "xla":
-        out = kref.streaming_q8_sim(
-            jp, Lq8, qp, lengths.astype(jnp.int32), w3q, scales, nx, f=f
-        )
+        out = kref.streaming_q8_sim(jp, Lq8, qp, lens, w3q, scales, nx, f=f)
     else:
         out = streaming_step_pallas_q8(
-            jp, Lq8, qp, lengths.astype(jnp.int32), w3q, scales, nx,
+            jp, Lq8, qp, lens, w3q, scales, nx,
             f=f, chunk_t=chunk_t, interpret=(backend == "interpret"),
         )
-    return out[:, :ny] + b.astype(jnp.float32)
+    return out[:bsz, :ny] + b.astype(jnp.float32)
 
 
 @functools.partial(

@@ -38,7 +38,10 @@ masking bit for bit.
 
 Grid: (batch_blocks, time_chunks), time minor/sequential so the scratch
 carries across chunks (re-initialized at chunk 0 of every batch block).
-Same ring-padding contract as the other kernels (``ops._ring_padded``):
+The ring-mix dot runs at ``Precision.HIGHEST``: the chip's default f32
+matmul rounds its operands to bf16, which the recurrence would carry
+through every step.  Same ring-padding contract as the other kernels
+(``ops._ring_padded``):
 L/qpow are built for the padded node count with the true last node
 mirrored into the last padded lane so the in-kernel ring wrap
 ``x_prev[:, -1:]`` reads node Nx-1.
@@ -64,6 +67,7 @@ import jax.experimental.pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import reservoir as core_res
+from repro.core.types import DOT_PRECISION
 
 
 def _train_forward_kernel(
@@ -105,7 +109,8 @@ def _train_forward_kernel(
         x_prev = state[...]
         j_k = j_ref[t, :, :]                      # (block_b, n_pad)
         a = p * f(j_k + x_prev)
-        x_k = jax.lax.dot(a, Lt, preferred_element_type=jnp.float32) \
+        x_k = jax.lax.dot(a, Lt, precision=DOT_PRECISION,
+                          preferred_element_type=jnp.float32) \
             + x_prev[:, -1:] * qpow
         k_global = tc * chunk_t + t
         # latch the truncation boundary BEFORE the state update: at the
@@ -271,7 +276,8 @@ def train_forward_scan(
         # sum_k x(k).x(k-1)^T over the chunk: the shifted pairing is
         # slices of the same stack (x1m[k] pairs with xs[k-1]) plus the
         # chunk-seam term x1m[0].x_in^T; the state sum rides separately
-        out_a = out_a + jnp.einsum(eq, x1m[1:], xs[:-1].astype(jnp.float32))
+        out_a = out_a + jnp.einsum(eq, x1m[1:], xs[:-1].astype(jnp.float32),
+                                   precision=DOT_PRECISION)
         out_a = out_a + (x1m[0][..., :, None]
                          * x_in.astype(jnp.float32)[..., None, :])
         return out_a, sum_a + x1m.sum(axis=0)
@@ -286,7 +292,8 @@ def train_forward_scan(
 
             def step(x_prev, j_k):
                 a = p * f(j_k + x_prev)
-                x_k = a @ Lt + x_prev[..., -1:] * qpow
+                x_k = (jnp.matmul(a, Lt, precision=DOT_PRECISION)
+                       + x_prev[..., -1:] * qpow)
                 return x_k, x_k
 
             x_out, xs = jax.lax.scan(step, x_in, j_chunk)
@@ -300,7 +307,8 @@ def train_forward_scan(
             def step(c2, j_k):
                 x_prev, k, x_bnd, j_bnd = c2
                 a = p * f(j_k + x_prev)
-                x_k = a @ Lt + x_prev[..., -1:] * qpow
+                x_k = (jnp.matmul(a, Lt, precision=DOT_PRECISION)
+                       + x_prev[..., -1:] * qpow)
                 is_bnd = k == lengths - 1
                 live = k < lengths
                 if batched:

@@ -60,6 +60,12 @@ COLLECTIVES = ("all-gather", "all-reduce", "reduce-scatter", "all-to-all",
                "collective-permute")
 
 
+def collectives_in(text: str) -> List[str]:
+    """The collective ops (sync or async ``-start`` form) an HLO text uses."""
+    return [op for op in COLLECTIVES
+            if re.search(rf"\b{op}(-start)?\(", text)]
+
+
 def _shape_bytes(dtype: str, dims: str) -> float:
     n = 1
     if dims:

@@ -10,11 +10,20 @@ from typing import Optional
 import jax
 
 
+def _auto_mesh(shape, axes):
+    """``jax.make_mesh`` with Auto axes: the model code places arrays with
+    ``NamedSharding``s and leaves propagation to the compiler, which
+    Explicit axes (``make_mesh``'s default) refuse for its gathers."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 single-pod (256 chips) or 2x16x16 multi-pod (512 chips)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(data: Optional[int] = None, model: int = 1):
@@ -22,7 +31,7 @@ def make_host_mesh(data: Optional[int] = None, model: int = 1):
     n = jax.device_count()
     if data is None:
         data = n // model
-    return jax.make_mesh((data, model), ("data", "model"))
+    return _auto_mesh((data, model), ("data", "model"))
 
 
 def make_slot_mesh(n_slot: Optional[int] = None, member: int = 1):

@@ -60,7 +60,8 @@ from repro.core.candidates import (
     survivor_parents,
 )
 from repro.core.online import OnlineState
-from repro.core.types import Array, DFRConfig, QuantParams, RidgeState
+from repro.core.types import (DOT_PRECISION, Array, DFRConfig, QuantParams,
+                              RidgeState)
 
 # beta search box (log10): spans the typical cfg.betas sweep
 BETA_LOG_RANGE = (-4.0, 0.0)
@@ -113,15 +114,18 @@ def _evaluate_triples(
     rt_val = dprr.r_tilde(vfeats(ps, qs, val_u, val_len))    # (K, Bv, s)
 
     s = rt_fit.shape[-1]
-    A = jnp.einsum("by,kbs->kys", y_fit, rt_fit)             # (K, Ny, s)
-    Bm = jnp.einsum("kbs,kbt->kst", rt_fit, rt_fit)          # (K, s, s)
+    A = jnp.einsum("by,kbs->kys", y_fit, rt_fit,
+                   precision=DOT_PRECISION)                  # (K, Ny, s)
+    Bm = jnp.einsum("kbs,kbt->kst", rt_fit, rt_fit,
+                    precision=DOT_PRECISION)                 # (K, s, s)
     Breg = Bm + betas[:, None, None] * jnp.eye(s, dtype=Bm.dtype)
     C = jnp.linalg.cholesky(Breg)
     Wt = jax.vmap(
         lambda c, a: jax.scipy.linalg.cho_solve((c, True), a.T).T
     )(C, A)                                                  # (K, Ny, s)
 
-    pred = jnp.einsum("kbs,kys->kby", rt_val, Wt)            # (K, Bv, Ny)
+    pred = jnp.einsum("kbs,kys->kby", rt_val, Wt,
+                      precision=DOT_PRECISION)               # (K, Bv, Ny)
     var = jnp.mean(jnp.square(y_val - jnp.mean(y_val))) + 1e-12
     err = pred - y_val[None]
     nrmse = jnp.sqrt(jnp.mean(err * err, axis=(1, 2)) / var)

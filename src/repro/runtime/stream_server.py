@@ -137,12 +137,14 @@ window gather, truncated-BP/accumulation, cohort Ridge refresh and sample
 retirement all touch only the local block, and a live slot never migrates
 between devices.  The ``lax.cond`` gates become per-device predicates
 (``jnp.any`` over the local shard) whose untaken branches are exact
-identities, so a sharded episode is BITWISE the single-device episode
-across every retirement mode and pipeline depth
-(``tests/test_stream_sharded.py``).  Donation, zero-copy staging and the
-fused cohort refresh all survive sharding: payload uploads happen once
-(the owning device keeps the in-place row write, the others drop it), and
-a serving step is still ONE dispatch.  Try it on CPU with
+identities, so a sharded episode serves the single-device episode's
+predictions across every retirement mode and pipeline depth, with states
+equal under ``runtime.parity``'s rule: XLA compiles the slot-vmapped step
+for the device-local slot count, and a few per-slot reductions then round
+in another order (``tests/test_stream_sharded.py``).  Donation, zero-copy
+staging and the fused cohort refresh all survive sharding: payload uploads
+happen once (the owning device keeps the in-place row write, the others
+drop it), and a serving step is still ONE dispatch.  Try it on CPU with
 ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` (e.g.
 ``python examples/online_edge.py --devices 8``).
 
@@ -193,7 +195,6 @@ from typing import Deque, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import masking, online, ridge
@@ -346,9 +347,11 @@ def _step_core(
     per-slot loss-EMA breakpoint detector (``online.adaptive_anneal``)
     on the serve step's own loss metric - the ``forget`` operand becomes
     the fire-time lambda, applied through a traced (S,) per-slot forget
-    vector only to tripped slots, cond-gated so a silent step is bitwise
-    the ``retirement='none'`` step on everything but the two detector
-    EMA leaves.
+    vector only to tripped slots, cond-gated so a silent step is the
+    ``retirement='none'`` step on everything but the two detector EMA
+    leaves (under ``runtime.parity``'s rule: the loss EMA can round one
+    ulp apart, because XLA fuses the forward differently around the
+    detector).
 
     ``quantize='int8'`` (static) serves ARMED slots from the int8 fast
     path (``ops.streaming_logits_slots_q8``: coded reservoir state +
@@ -523,7 +526,7 @@ def _step_core(
         # already produced: EMAs update for live slots that folded
         # frozen-phase samples; a tripped slot's statistics anneal by the
         # traced (S,) forget vector (lam=1.0 elsewhere), cond-gated on any
-        # trip so the silent path stays bitwise retirement='none'.  Runs
+        # trip so the silent path stays retirement='none'.  Runs
         # AFTER the factor fold: the anneal scales the post-fold factor
         # consistently (Lt by sqrt(lam), factor_beta by lam).  A tripped
         # int8 slot needs no special handling - its quant scales re-fold
@@ -802,11 +805,12 @@ _stream_step_pool_block_donated = jax.jit(
 # the cursor gather, the serve step, cohort refresh and retirement all act
 # on the device-local slot block.  The lax.cond gates inside _step_core
 # become per-device predicates (jnp.any over the local shard); an untaken
-# branch is the exact identity, so the sharded episode is BITWISE the
-# single-device episode (tests/test_stream_sharded.py holds this across
-# device counts, retirement modes and pipeline depths).  Donation flows
-# through jit(shard_map): out_specs match the donated operands' shardings,
-# so the (S/n, s, s) factor buffers still update in place per device.
+# branch is the exact identity, so the sharded episode is the single-device
+# episode under runtime.parity's rule (tests/test_stream_sharded.py holds
+# this across device counts, retirement modes and pipeline depths).
+# Donation flows through jit(shard_map): out_specs match the donated
+# operands' shardings, so the (S/n, s, s) factor buffers still update in
+# place per device.
 # ---------------------------------------------------------------------------
 
 _SLOT, _REP = P("slot"), P()
@@ -835,10 +839,10 @@ def _sharded_pool_step(mesh: Mesh, cfg: DFRConfig, donate: bool, **statics):
     key = (mesh, cfg, donate, tuple(sorted(statics.items())))
     hit = _SHARDED_STEP_CACHE.get(key)
     if hit is None:
-        body = shard_map(
+        body = jax.shard_map(
             partial(_stream_step_pool_impl, cfg, **statics),
             mesh=mesh, in_specs=_POOL_IN_SPECS, out_specs=_POOL_OUT_SPECS,
-            check_rep=False,
+            check_vma=False,
         )
         hit = _SHARDED_STEP_CACHE[key] = jax.jit(
             body, donate_argnums=(1, 11) if donate else ()
@@ -852,15 +856,15 @@ def _sharded_pool_block_step(
     """jit(shard_map(_stream_step_pool_block_impl)): the step-blocked scan
     with every sub-step acting on the device-local slot block.  The scan
     carries only slot-sharded or replicated values and the body is the
-    collective-free pool step, so a blocked sharded episode is bitwise the
+    collective-free pool step, so a blocked sharded episode matches the
     blocked single-device episode (same argument as the unblocked twin)."""
     key = ("block", mesh, cfg, donate, tuple(sorted(statics.items())))
     hit = _SHARDED_STEP_CACHE.get(key)
     if hit is None:
-        body = shard_map(
+        body = jax.shard_map(
             partial(_stream_step_pool_block_impl, cfg, **statics),
             mesh=mesh, in_specs=_BLOCK_IN_SPECS, out_specs=_BLOCK_OUT_SPECS,
-            check_rep=False,
+            check_vma=False,
         )
         hit = _SHARDED_STEP_CACHE[key] = jax.jit(
             body, donate_argnums=(1, 11) if donate else ()
@@ -892,10 +896,10 @@ def _pool_write_sharded_impl(
 def _sharded_pool_write(mesh: Mesh):
     hit = _SHARDED_WRITE_CACHE.get(mesh)
     if hit is None:
-        body = shard_map(
+        body = jax.shard_map(
             _pool_write_sharded_impl, mesh=mesh,
             in_specs=(_SLOT, _REP, _REP, _REP, _REP, _REP),
-            out_specs=_SLOT, check_rep=False,
+            out_specs=_SLOT, check_vma=False,
         )
         hit = _SHARDED_WRITE_CACHE[mesh] = jax.jit(
             body, donate_argnums=(0,)
@@ -1021,7 +1025,7 @@ class StreamServer:
         and the detector re-arms.  No per-sample decay, no window buffer,
         no extra knobs to hand-tune per stream.  The equivalence
         contract: an episode in which the detector never fires serves
-        bit-for-bit the ``retirement='none'`` episode.
+        the ``retirement='none'`` episode (``runtime.parity``'s rule).
 
     Serving pipeline (PR 5, see the module docstring):
 
@@ -1427,8 +1431,20 @@ class StreamServer:
             )
 
     def _snapshot_row(self, i: int) -> OnlineState:
-        """Copy of slot i's state (the retiring stream's final model)."""
-        return _snapshot_slot(self.states, jnp.asarray(i))
+        """Copy of slot i's state (the retiring stream's final model).  On
+        a slot mesh the copy is read from the owning device's shard:
+        indexing the sharded batch itself gathers every device's (S/n, s, s)
+        block for each retirement."""
+        if self.mesh is None:
+            return _snapshot_slot(self.states, jnp.asarray(i))
+        owner, row = divmod(i, self.states.step.shape[0] // self.devices)
+        dev = self.mesh.devices.flat[owner]
+        local = jax.tree_util.tree_map(
+            lambda leaf: next(sh.data for sh in leaf.addressable_shards
+                              if sh.device == dev),
+            self.states,
+        )
+        return _snapshot_slot(local, np.int32(row))
 
     def _cached_mask(self, mask_np: np.ndarray) -> Array:
         """Device copy of a small (S,) bool control mask, cached by value."""
@@ -1538,26 +1554,13 @@ class StreamServer:
                     n = min(W, req.n_samples - lo)
                     meta.append((t, i, req, lo, n))
 
-        step_kw = dict(
-            fused_infer=self.fused_infer,
-            maintain_factor=(self.refresh_mode == "incremental"),
-            retirement=self.retirement,
-            adapt_ratio=self.adapt_ratio,
-            adapt_warmup=self.adapt_warmup,
-            chunk_t=self.chunk_t,
-        )
+        step_kw = self._step_kw()
         if self.staging == "device":
             pool_kw = dict(
                 refresh_mode=self.refresh_mode, window=W,
                 quantize=self.quantize, **step_kw,
             )
-            operands = (
-                self.mask, self.states, self._fresh_row,
-                self._cached_mask(fresh_mask), self.pool,
-                jnp.asarray(self.slot_pos.astype(np.int32)),
-                self._cached_mask(live), self.lr, self.phase_steps,
-                self.beta, self.forget, self.win,
-            )
+            operands = self._pool_operands(fresh_mask, live)
             if self.step_block > 1:
                 active, due_b, rows_b, ok_b = self._cached_due_block(
                     self.global_step + 1, b_active
@@ -1578,20 +1581,8 @@ class StreamServer:
                     )
                 self.global_step += b_active
             else:
-                due, rows, ok = self._cached_due(self.global_step + 1)
-                if self.mesh is not None:
-                    step_fn = _sharded_pool_step(
-                        self.mesh, self.cfg, self.donate, **pool_kw
-                    )
-                    self.states, self.win, preds = step_fn(
-                        *operands, due, rows, ok
-                    )
-                else:
-                    step_fn = (_stream_step_pool_donated if self.donate
-                               else _stream_step_pool)
-                    self.states, self.win, preds = step_fn(
-                        self.cfg, *operands, due, rows, ok, **pool_kw,
-                    )
+                step_fn, args, kw = self._pool_step_call(operands, pool_kw)
+                self.states, self.win, preds = step_fn(*args, **kw)
                 self.global_step += 1
         else:
             # PR-4 host staging: rebuild + upload the padded window batch
@@ -1650,6 +1641,52 @@ class StreamServer:
         while len(self._inflight) > self.pipeline_depth:
             self._drain_one()
         self.step_times_s.append(time.perf_counter() - t_start)
+
+    def _step_kw(self) -> Dict:
+        return dict(
+            fused_infer=self.fused_infer,
+            maintain_factor=(self.refresh_mode == "incremental"),
+            retirement=self.retirement,
+            adapt_ratio=self.adapt_ratio,
+            adapt_warmup=self.adapt_warmup,
+            chunk_t=self.chunk_t,
+        )
+
+    def _pool_operands(self, fresh_mask: np.ndarray, live: np.ndarray):
+        return (
+            self.mask, self.states, self._fresh_row,
+            self._cached_mask(fresh_mask), self.pool,
+            jnp.asarray(self.slot_pos.astype(np.int32)),
+            self._cached_mask(live), self.lr, self.phase_steps,
+            self.beta, self.forget, self.win,
+        )
+
+    def _pool_step_call(self, operands: Tuple, pool_kw: Dict):
+        """(jitted program, args, kwargs) of one unblocked device-staged
+        step with the refresh schedule of the next global step."""
+        due, rows, ok = self._cached_due(self.global_step + 1)
+        if self.mesh is not None:
+            step_fn = _sharded_pool_step(
+                self.mesh, self.cfg, self.donate, **pool_kw
+            )
+            return step_fn, (*operands, due, rows, ok), {}
+        step_fn = (_stream_step_pool_donated if self.donate
+                   else _stream_step_pool)
+        return step_fn, (self.cfg, *operands, due, rows, ok), pool_kw
+
+    def step_program_text(self) -> str:
+        """Optimized HLO of the unblocked device-staged step program at the
+        server's current shapes: Pallas kernels appear as
+        ``tpu_custom_call``, cross-device traffic as collectives (the
+        slot-sharded step has none)."""
+        if self.staging != "device":
+            raise ValueError("step_program_text needs staging='device'")
+        idle = np.zeros((self.max_streams,), bool)
+        pool_kw = dict(refresh_mode=self.refresh_mode, window=self.window,
+                       quantize=self.quantize, **self._step_kw())
+        step_fn, args, kw = self._pool_step_call(
+            self._pool_operands(idle, idle), pool_kw)
+        return step_fn.lower(*args, **kw).compile().as_text()
 
     def _drain_one(self) -> None:
         """Materialize the oldest in-flight step's predictions (the only

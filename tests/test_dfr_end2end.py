@@ -68,7 +68,6 @@ def test_distributed_readout_single_device_path(jpvow_small):
 
 def test_distributed_readout_psum_consistency(jpvow_small):
     """shard_map over 1-device mesh: psum path == local path exactly."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     train, _ = jpvow_small
@@ -86,7 +85,7 @@ def test_distributed_readout_psum_consistency(jpvow_small):
         st = ro_dist.accumulate(rs, params, h, lab)
         return ro_dist.solve(st, params, jnp.float32(1e-2)).W
 
-    dist_W = shard_map(
+    dist_W = jax.shard_map(
         shard_fn, mesh=mesh, in_specs=(P("data"), P("data")), out_specs=P()
     )(h, lab)
     np.testing.assert_allclose(np.asarray(local_W), np.asarray(dist_W),

@@ -243,7 +243,6 @@ def test_online_step_psum_matches_unsharded():
     """online_step(axis_names=('data',)) inside shard_map over a 1-device
     data mesh reproduces the plain step exactly ((A, B)/grad sums are
     associative, so the psum is the identity at world size 1)."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec
 
     from repro.core import online
@@ -262,14 +261,14 @@ def test_online_step_psum_matches_unsharded():
 
     mesh = jax.make_mesh((1,), ("data",))
     P_ = PartitionSpec
-    sharded = shard_map(
+    sharded = jax.shard_map(
         lambda st, uu, ll, yy: online.online_step(
             cfg, system.mask, st, uu, ll, yy, lr, lr, axis_names=("data",)
         ),
         mesh=mesh,
         in_specs=(P_(), P_("data"), P_("data"), P_("data")),
         out_specs=P_(),
-        check_rep=False,
+        check_vma=False,
     )
     got_state, got_metrics = jax.jit(sharded)(state, u, ln, lab)
     for a, b in zip(jax.tree_util.tree_leaves(ref_state),

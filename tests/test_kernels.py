@@ -157,7 +157,8 @@ def test_dprr_kernel_single_sample_matches_manual():
 @pytest.mark.parametrize("b,t,nx,ny,chunk,f_name",
                          [(3, 50, 30, 4, 64, "linear"),
                           (2, 130, 17, 9, 128, "linear"),
-                          (4, 64, 8, 2, 64, "tanh")])
+                          (4, 64, 8, 2, 64, "tanh"),
+                          (12, 40, 30, 10, 64, "linear")])
 def test_streaming_kernel_matches_unfused(b, t, nx, ny, chunk, f_name):
     """Fused streaming step (reservoir -> DPRR -> readout in one kernel)
     vs the unfused XLA composition, across lengths/padding/nonlinearity."""

@@ -301,6 +301,20 @@ def test_q8_kernel_matches_its_oracle_exactly():
                                rtol=1e-6, atol=1e-6)
 
 
+def test_q8_kernel_pads_batch_past_one_block():
+    """B = 12 runs as two 8-row blocks (the batch padded to 16 with
+    length-0 rows); the logical (B, ny) logits still match the oracle."""
+    j, lengths, p, q, W, Wq, w_scale, b, nx = _quant_operands(nb=12)
+    x_scale = jnp.float32(0.02)
+    out_xla = ops.streaming_logits_q8(
+        j, lengths, p, q, Wq, w_scale, x_scale, b, nx, backend="xla")
+    out_itp = ops.streaming_logits_q8(
+        j, lengths, p, q, Wq, w_scale, x_scale, b, nx, backend="interpret")
+    assert out_itp.shape == (12, W.shape[0])
+    np.testing.assert_allclose(np.asarray(out_itp), np.asarray(out_xla),
+                               rtol=1e-6, atol=1e-6)
+
+
 def test_q8_logits_track_fp32_within_band():
     """With calibrated scales the int8 logits stay near the fp32 fused
     logits - the honest quantization-noise band at Nx=8."""
