@@ -136,7 +136,7 @@ def _print_tuner(tuner) -> None:
 
 
 def _fmt_ms(v) -> str:
-    """A latency percentile for humans: NaN means 'no records', never a
+    """A step-time percentile for humans: NaN means 'no records', never a
     fake 0.0 ms reading."""
     return "n/a" if np.isnan(v) else f"{v:.1f} ms"
 
@@ -210,10 +210,10 @@ def run_drift(args) -> None:
               f"{at:.3f} / post {post:.3f} "
               f"({int(r.final_state.ridge.count)} samples in (A,B))")
     lat = server.latency_percentiles_ms()
-    print(f"  window-round latency p50 {_fmt_ms(lat['p50_ms'])} / "
+    print(f"  step wall time p50 {_fmt_ms(lat['p50_ms'])} / "
           f"p99 {_fmt_ms(lat['p99_ms'])} over {server.global_step} rounds "
           f"(p99 absorbs the one-time jit compile at these few rounds; "
-          f"bench_stream reports warmed steady-state latency)")
+          f"bench_stream reports warmed steady-state step times)")
     if server.pipeline_depth > 0:
         print(f"  pipeline depth {server.pipeline_depth}: dispatch p50 "
               f"{_fmt_ms(lat['dispatch_p50_ms'])}, drain (sync) p50 "
@@ -362,7 +362,7 @@ def main():
               f"{r.online_accuracy:.3f} "
               f"({int(r.final_state.ridge.count)} samples in (A,B))")
     lat = server.latency_percentiles_ms()
-    print(f"  window-round latency p50 {_fmt_ms(lat['p50_ms'])} / "
+    print(f"  step wall time p50 {_fmt_ms(lat['p50_ms'])} / "
           f"p99 {_fmt_ms(lat['p99_ms'])} over {server.global_step} rounds")
     if server.pipeline_depth > 0:
         print(f"  pipeline depth {server.pipeline_depth}: dispatch p50 "

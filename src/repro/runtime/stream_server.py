@@ -110,10 +110,10 @@ regression-tested bit-for-bit against the synchronous host-staged path):
   synchronizes.  Slot lifecycle (admission/retirement) is cursor-driven
   and therefore dispatch-time exact: pipelining delays only the *metric*
   bookkeeping, never the serving schedule, so ``pipeline_depth=0`` is
-  bit-for-bit ``pipeline_depth=D`` over any episode.  Latency is reported
-  honestly: ``latency_percentiles_ms`` separates dispatch time (host
-  enqueue, never blocking on device compute) from drain time (the actual
-  synchronization cost), so pipelining cannot hide its sync bill.
+  bit-for-bit ``pipeline_depth=D`` over any episode.  Step wall time is
+  reported honestly: ``latency_percentiles_ms`` separates dispatch time
+  (host enqueue, never blocking on device compute) from drain time (the
+  actual synchronization cost), so pipelining cannot hide its sync bill.
 
 ``bench_stream``'s ``pipeline`` table measures the three knobs against the
 PR-4 synchronous host-staged server (see ROADMAP "Landed (PR 5)" for the
@@ -209,6 +209,7 @@ from repro.core.types import Array, DFRConfig, RequestPool, WindowState
 from repro.distributed import sharding as shardrules
 from repro.kernels import ops
 from repro.launch.mesh import make_slot_mesh
+from repro.runtime import tracing
 from repro.runtime.scheduler import RefreshCohorts, SlotScheduler
 
 
@@ -380,20 +381,24 @@ def _step_core(
             st, fresh,
         )
 
-    states = jax.lax.cond(jnp.any(fresh_mask), _admit, lambda st: st, states)
-    if retirement == "window":
-        # admitted slots also restart their ring buffer (same cond gating)
-        win = jax.lax.cond(
-            jnp.any(fresh_mask),
-            lambda w: jax.tree_util.tree_map(
-                lambda leaf: jnp.where(
-                    _bcast_to(fresh_mask, leaf), jnp.zeros_like(leaf), leaf
-                ),
-                w,
-            ),
-            lambda w: w,
-            win,
+    with jax.named_scope("stream.admit_reset"):
+        states = jax.lax.cond(
+            jnp.any(fresh_mask), _admit, lambda st: st, states
         )
+        if retirement == "window":
+            # admitted slots also restart their ring buffer (same gating)
+            win = jax.lax.cond(
+                jnp.any(fresh_mask),
+                lambda w: jax.tree_util.tree_map(
+                    lambda leaf: jnp.where(
+                        _bcast_to(fresh_mask, leaf), jnp.zeros_like(leaf),
+                        leaf,
+                    ),
+                    w,
+                ),
+                lambda w: w,
+                win,
+            )
 
     # per-slot learning-rate phase: adapt (p, q, W, b) while the slot is
     # young, then freeze the reservoir for consistent Ridge features; the
@@ -427,34 +432,37 @@ def _step_core(
     # the same episode and the cond only sheds dead compute.  The cond sits
     # OUTSIDE the vmap: vmapping a cond would lower to a select that runs
     # both branches for every lane.
-    new_states, logits, metrics = jax.lax.cond(
-        jnp.any(in_phase1 & live), _serve_all(True), _serve_all(False),
-        (states, u, length, label, weight, lr_slot, acc_slot),
-    )
+    with jax.named_scope("stream.serve"):
+        new_states, logits, metrics = jax.lax.cond(
+            jnp.any(in_phase1 & live), _serve_all(True), _serve_all(False),
+            (states, u, length, label, weight, lr_slot, acc_slot),
+        )
 
     if fused_infer:
         # route inference through the fused streaming kernel
         # (kernels.streaming: reservoir -> DPRR -> readout in one kernel
         # call, the TPU latency path; its XLA ref is the same math as the
         # shared forward, so on CPU this only adds the extra pass)
-        j_seq = masking.apply_mask(mask, u)
-        logits = ops.streaming_logits_slots(
-            j_seq, length, states.params.p, states.params.q,
-            states.params.W, states.params.b, cfg.n_nodes, f=f,
-            chunk_t=chunk_t,
-        )
+        with jax.named_scope("stream.kernel"):
+            j_seq = masking.apply_mask(mask, u)
+            logits = ops.streaming_logits_slots(
+                j_seq, length, states.params.p, states.params.q,
+                states.params.W, states.params.b, cfg.n_nodes, f=f,
+                chunk_t=chunk_t,
+            )
     if quantize == "int8":
         # int8 fast path for ARMED slots (scales folded at least once):
         # pre-update coded readout + coded recurrent state, integer
         # reservoir/DPRR/readout compute, fp32 dequantized logits.  Unarmed
         # slots (w_scale == 0: no refresh boundary crossed yet) keep the
         # fp32 logits computed above - the select is per slot lane.
-        j_seq = masking.apply_mask(mask, u)
-        q_logits = ops.streaming_logits_slots_q8(
-            j_seq, length, states.params.p, states.params.q,
-            states.quant.Wq, states.quant.w_scale, states.quant.x_scale,
-            states.params.b, cfg.n_nodes, f=f, chunk_t=chunk_t,
-        )
+        with jax.named_scope("stream.kernel"):
+            j_seq = masking.apply_mask(mask, u)
+            q_logits = ops.streaming_logits_slots_q8(
+                j_seq, length, states.params.p, states.params.q,
+                states.quant.Wq, states.quant.w_scale, states.quant.x_scale,
+                states.params.b, cfg.n_nodes, f=f, chunk_t=chunk_t,
+            )
         armed = states.quant.w_scale > 0
         logits = jnp.where(
             armed[:, None, None], q_logits.astype(logits.dtype), logits
@@ -463,64 +471,68 @@ def _step_core(
 
     # dead slots keep their state untouched (cond-gated like admission:
     # a fully-live step - the steady state - pays no copy)
-    new_states = jax.lax.cond(
-        jnp.all(live),
-        lambda pair: pair[0],
-        lambda pair: jax.tree_util.tree_map(
-            lambda n, o: jnp.where(_bcast_to(live, n), n, o), *pair
-        ),
-        (new_states, states),
-    )
-    if maintain_factor:
-        # deferred rank-1 fold of the window into each slot's live factor
-        # (the rows are exactly the gated r~ rows accumulated into B above:
-        # dead/tail/adaptation-phase rows are zero, hence exact no-ops)
-        rt_rows = metrics.pop("rt_rows")
-        if retirement == "forget":
-            scales = metrics.pop("fold_scale")
-            Lt = jax.vmap(ridge.cholupdate_window_t_decay)(
-                new_states.ridge.Lt, rt_rows, scales
-            )
-        else:
-            Lt = jax.vmap(ridge.cholupdate_window_t)(
-                new_states.ridge.Lt, rt_rows
-            )
-        new_states = dataclasses.replace(
-            new_states,
-            ridge=dataclasses.replace(new_states.ridge, Lt=Lt),
+    with jax.named_scope("stream.live_select"):
+        new_states = jax.lax.cond(
+            jnp.all(live),
+            lambda pair: pair[0],
+            lambda pair: jax.tree_util.tree_map(
+                lambda n, o: jnp.where(_bcast_to(live, n), n, o), *pair
+            ),
+            (new_states, states),
         )
-        if retirement == "window":
-            # retire the oldest retained sample per accumulated row: evict
-            # from (A, B), downdate out of the live factor, refill the ring
-            gate = weight * acc_slot[:, None]            # (S, W) 0/1
-            oh_rows = jax.nn.one_hot(label, cfg.n_classes, dtype=cfg.dtype)
-            Lt, A, B, count, win, bad = jax.vmap(_retire_window_slot)(
-                new_states.ridge.Lt, new_states.ridge.A, new_states.ridge.B,
-                new_states.ridge.count, win, rt_rows, oh_rows, gate,
-            )
-            # guard fallback: a clamp-skipped downdate left that slot's
-            # factor stale - rebuild it from the retained B + beta I.  The
-            # batched factorization is cond-gated on ANY slot flagging, so
-            # the clean path (every realistic step) never pays it.
-            Lt = jax.lax.cond(
-                jnp.any(bad),
-                lambda args: jnp.where(
-                    bad[:, None, None],
-                    jnp.swapaxes(
-                        jnp.linalg.cholesky(ridge.regularize(args[1], beta)),
-                        -1, -2,
-                    ),
-                    args[0],
-                ),
-                lambda args: args[0],
-                (Lt, B),
-            )
+    if maintain_factor:
+        with jax.named_scope("stream.factor_fold"):
+            # deferred rank-1 fold of the window into each slot's live factor
+            # (the rows are exactly the gated r~ rows accumulated into B above:
+            # dead/tail/adaptation-phase rows are zero, hence exact no-ops)
+            rt_rows = metrics.pop("rt_rows")
+            if retirement == "forget":
+                scales = metrics.pop("fold_scale")
+                Lt = jax.vmap(ridge.cholupdate_window_t_decay)(
+                    new_states.ridge.Lt, rt_rows, scales
+                )
+            else:
+                Lt = jax.vmap(ridge.cholupdate_window_t)(
+                    new_states.ridge.Lt, rt_rows
+                )
             new_states = dataclasses.replace(
                 new_states,
-                ridge=dataclasses.replace(
-                    new_states.ridge, Lt=Lt, A=A, B=B, count=count
-                ),
+                ridge=dataclasses.replace(new_states.ridge, Lt=Lt),
             )
+            if retirement == "window":
+                # retire the oldest retained sample per accumulated row: evict
+                # from (A, B), downdate out of the live factor, refill the ring
+                gate = weight * acc_slot[:, None]            # (S, W) 0/1
+                oh_rows = jax.nn.one_hot(label, cfg.n_classes, dtype=cfg.dtype)
+                rs = new_states.ridge
+                Lt, A, B, count, win, bad = jax.vmap(_retire_window_slot)(
+                    rs.Lt, rs.A, rs.B, rs.count, win, rt_rows, oh_rows, gate,
+                )
+                # guard fallback: a clamp-skipped downdate left that slot's
+                # factor stale - rebuild it from the retained B + beta I.  The
+                # batched factorization is cond-gated on ANY slot flagging, so
+                # the clean path (every realistic step) never pays it.
+                Lt = jax.lax.cond(
+                    jnp.any(bad),
+                    lambda args: jnp.where(
+                        bad[:, None, None],
+                        jnp.swapaxes(
+                            jnp.linalg.cholesky(
+                                ridge.regularize(args[1], beta)
+                            ),
+                            -1, -2,
+                        ),
+                        args[0],
+                    ),
+                    lambda args: args[0],
+                    (Lt, B),
+                )
+                new_states = dataclasses.replace(
+                    new_states,
+                    ridge=dataclasses.replace(
+                        new_states.ridge, Lt=Lt, A=A, B=B, count=count
+                    ),
+                )
     if retirement == "adaptive":
         # per-slot drift detection on the serving error rate the serve step
         # already produced: EMAs update for live slots that folded
@@ -653,9 +665,10 @@ def _stream_step_pool_impl(
     serving path is never staler than one refresh cadence, and scale
     refreshes ride the existing dispatch for free.
     """
-    u, length, label, weight = _gather_window(
-        pool, cursor, live, window, cfg.dtype
-    )
+    with jax.named_scope("stream.gather"):
+        u, length, label, weight = _gather_window(
+            pool, cursor, live, window, cfg.dtype
+        )
     new_states, win, preds, _ = _step_core(
         cfg, mask, states, fresh, fresh_mask, u, length, label, weight,
         live, lr, phase_steps, beta, forget, win,
@@ -680,9 +693,10 @@ def _stream_step_pool_impl(
             st = online.fold_quant_rows(st, refresh_rows, el)
         return st
 
-    new_states = jax.lax.cond(
-        refresh_due, _refresh, lambda st: st, new_states
-    )
+    with jax.named_scope("stream.refresh"):
+        new_states = jax.lax.cond(
+            refresh_due, _refresh, lambda st: st, new_states
+        )
     return new_states, win, preds
 
 
@@ -1227,6 +1241,7 @@ class StreamServer:
         self.window = int(window)
         self.lr = jnp.asarray(lr, cfg.dtype)
         self.phase_steps = jnp.asarray(phase_steps, jnp.int32)
+        self._phase_steps = int(phase_steps)   # host copy for span stats
         self.refresh_every = int(refresh_every)
         self.beta = jnp.asarray(beta, cfg.dtype)
         self.refresh_mode = refresh_mode
@@ -1334,9 +1349,9 @@ class StreamServer:
         self._due_block_cache: Dict[Tuple, Tuple] = {}
         self.global_step = 0
         self._autotuner = None  # optional WarmPoolAutotuner (attach_autotuner)
-        # async pipeline: (device preds, per-slot bookkeeping meta) entries,
-        # drained once more than pipeline_depth steps are in flight
-        self._inflight: Deque[Tuple[Array, List[Tuple]]] = deque()
+        # async pipeline: (device preds, per-slot bookkeeping meta, global
+        # step) entries, drained once more than pipeline_depth are in flight
+        self._inflight: Deque[Tuple[Array, List[Tuple], int]] = deque()
         # bounded latency records (ring buffers): total per-step wall time,
         # plus the honest split into non-blocking dispatch vs blocking drain
         self.step_times_s: Deque[float] = deque(maxlen=latency_window)
@@ -1352,20 +1367,25 @@ class StreamServer:
     def _stage_request(self, req: StreamRequest) -> None:
         """Pad + upload the stream's full payload ONCE (submit-time): the
         per-step path never touches the sample arrays again."""
-        cap = self._round_capacity(req.n_samples)
-        if cap > self.pool.capacity:
-            self._grow_pool(cap)
-        cap = self.pool.capacity
-        u = np.zeros((cap, self.t_max, self.cfg.n_in), self._np_dtype)
-        u[: req.n_samples] = req.u
-        length = np.ones((cap,), np.int32)
-        length[: req.n_samples] = req.length
-        label = np.zeros((cap,), np.int32)
-        label[: req.n_samples] = req.label
-        self._staged[id(req)] = (
-            jnp.asarray(u), jnp.asarray(length), jnp.asarray(label),
-            jnp.asarray(req.n_samples, jnp.int32), cap,
-        )
+        with tracing.span("stream.stage", rid=req.rid) as span:
+            cap = self._round_capacity(req.n_samples)
+            if cap > self.pool.capacity:
+                self._grow_pool(cap)
+            cap = self.pool.capacity
+            u = np.zeros((cap, self.t_max, self.cfg.n_in), self._np_dtype)
+            u[: req.n_samples] = req.u
+            length = np.ones((cap,), np.int32)
+            length[: req.n_samples] = req.length
+            label = np.zeros((cap,), np.int32)
+            label[: req.n_samples] = req.label
+            self._staged[id(req)] = (
+                jnp.asarray(u), jnp.asarray(length), jnp.asarray(label),
+                jnp.asarray(req.n_samples, jnp.int32), cap,
+            )
+            if tracing.recording():
+                span.set_metadata(
+                    bytes=u.nbytes + length.nbytes + label.nbytes
+                )
 
     def _grow_pool(self, cap: int) -> None:
         """Grow every slot row to ``cap`` samples (new longest stream).
@@ -1414,9 +1434,11 @@ class StreamServer:
     def _on_admit(self, i: int, req: StreamRequest) -> None:
         """Mark slot row i for the in-program fresh-state reset and write
         the staged payload into its pool row (one donated in-place write)."""
-        self.slot_pos[i] = 0
-        self._admitted_this_step.append(i)
-        if self.staging == "device":
+        with tracing.span("stream.admit", rid=req.rid, slot=i):
+            self.slot_pos[i] = 0
+            self._admitted_this_step.append(i)
+            if self.staging != "device":
+                return
             staged = self._staged.pop(id(req), None)
             if staged is None or staged[4] != self.pool.capacity:
                 # the pool grew (or the entry predates a growth): re-stage
@@ -1522,11 +1544,40 @@ class StreamServer:
         separate refresh dispatch.  Predictions enter the in-flight ring;
         entries deeper than ``pipeline_depth`` are drained (the only
         blocking device read), so depth 0 is fully synchronous.
+
+        The step is a ``stream.step`` span holding its ``stream.admit``,
+        ``stream.enqueue``, ``stream.retire`` and ``stream.drain`` spans
+        (``repro.runtime.tracing``: recorded only while a profiler runs,
+        with the step's counters as stats).
         """
-        t_start = time.perf_counter()
-        self._admitted_this_step.clear()
-        self.sched.admit(self._on_admit)
-        S, W, T = self.max_streams, self.window, self.t_max
+        with tracing.span("stream.step") as span:
+            t_start = time.perf_counter()
+            first = self.global_step + 1
+            self._admitted_this_step.clear()
+            self.sched.admit(self._on_admit)
+            live, fresh_mask, meta, b_active = self._plan_step()
+            due = any(self.cohorts.due_cohort(first + t) is not None
+                      for t in range(b_active))
+            with tracing.span("stream.enqueue", refresh=due):
+                preds = self._enqueue(fresh_mask, live, meta, b_active)
+            retired = self._retire(meta)
+            self._inflight.append((preds, meta, self.global_step))
+            if self._autotuner is not None:
+                self._autotuner.on_step()
+            self.dispatch_times_s.append(time.perf_counter() - t_start)
+            while len(self._inflight) > self.pipeline_depth:
+                self._drain_one()
+            self.step_times_s.append(time.perf_counter() - t_start)
+            if tracing.recording():
+                span.set_metadata(**self._step_stats(
+                    first, meta, b_active, int(live.sum()), retired
+                ))
+
+    def _plan_step(self) -> Tuple[np.ndarray, np.ndarray, List[Tuple], int]:
+        """(live, fresh_mask, meta, b_active) of this step: the (S,) live
+        and admission masks, one (sub-step, slot, request, cursor, samples)
+        entry per live slot and sub-step, and the sub-steps a block runs."""
+        S, W = self.max_streams, self.window
         live = np.zeros((S,), bool)
         fresh_mask = np.zeros((S,), bool)
         fresh_mask[self._admitted_this_step] = True
@@ -1553,7 +1604,15 @@ class StreamServer:
                     lo = int(self.slot_pos[i]) + t * W
                     n = min(W, req.n_samples - lo)
                     meta.append((t, i, req, lo, n))
+        return live, fresh_mask, meta, b_active
 
+    def _enqueue(
+        self, fresh_mask: np.ndarray, live: np.ndarray, meta: List[Tuple],
+        b_active: int,
+    ) -> Array:
+        """Enqueue this step's program(s) and advance ``global_step``;
+        returns the predictions, still on device."""
+        S, W, T = self.max_streams, self.window, self.t_max
         step_kw = self._step_kw()
         if self.staging == "device":
             pool_kw = dict(
@@ -1584,63 +1643,111 @@ class StreamServer:
                 step_fn, args, kw = self._pool_step_call(operands, pool_kw)
                 self.states, self.win, preds = step_fn(*args, **kw)
                 self.global_step += 1
-        else:
-            # PR-4 host staging: rebuild + upload the padded window batch
-            # (in cfg.dtype - the PR-4 code hardcoded float32 here, silently
-            # upcasting non-f32 configs)
-            u = np.zeros((S, W, T, self.cfg.n_in), self._np_dtype)
-            length = np.ones((S, W), np.int32)  # dead samples: len 1, w 0
-            label = np.zeros((S, W), np.int32)
-            weight = np.zeros((S, W), self._np_dtype)
-            for _t, i, req, lo, n in meta:
-                u[i, :n] = req.u[lo:lo + n]
-                length[i, :n] = req.length[lo:lo + n]
-                label[i, :n] = req.label[lo:lo + n]
-                weight[i, :n] = 1.0
-            step_fn = _stream_step_donated if self.donate else _stream_step
-            self.states, self.win, preds, _ = step_fn(
-                self.cfg, self.mask, self.states, self._fresh_row,
-                jnp.asarray(fresh_mask),
-                jnp.asarray(u), jnp.asarray(length), jnp.asarray(label),
-                jnp.asarray(weight), jnp.asarray(live), self.lr,
-                self.phase_steps, self.beta, self.forget, self.win, **step_kw,
-            )
-            self.global_step += 1
-            due = self.cohorts.due_slots(self.global_step)
-            if due is not None:
-                eligible = self._refresh_eligible(jnp.asarray(live))
-                if len(due) < self.max_streams:
-                    cohort = np.zeros((self.max_streams,), bool)
-                    cohort[due] = True
-                    eligible = eligible & jnp.asarray(cohort)
-                rows = jnp.asarray(due, jnp.int32)
-                if self.refresh_mode == "incremental":
-                    fn = (_stream_refresh_factor_rows_donated if self.donate
-                          else _stream_refresh_factor_rows)
-                    self.states = fn(self.states, eligible, rows)
-                else:
-                    fn = (_stream_refresh_rows_donated if self.donate
-                          else _stream_refresh_rows)
-                    self.states = fn(self.states, self.beta, eligible, rows)
+            return preds
+        # host staging: rebuild + upload the padded window batch (in
+        # cfg.dtype - an earlier version hardcoded float32 here, silently
+        # upcasting non-f32 configs)
+        u = np.zeros((S, W, T, self.cfg.n_in), self._np_dtype)
+        length = np.ones((S, W), np.int32)  # dead samples: len 1, w 0
+        label = np.zeros((S, W), np.int32)
+        weight = np.zeros((S, W), self._np_dtype)
+        for _t, i, req, lo, n in meta:
+            u[i, :n] = req.u[lo:lo + n]
+            length[i, :n] = req.length[lo:lo + n]
+            label[i, :n] = req.label[lo:lo + n]
+            weight[i, :n] = 1.0
+        step_fn = _stream_step_donated if self.donate else _stream_step
+        self.states, self.win, preds, _ = step_fn(
+            self.cfg, self.mask, self.states, self._fresh_row,
+            jnp.asarray(fresh_mask),
+            jnp.asarray(u), jnp.asarray(length), jnp.asarray(label),
+            jnp.asarray(weight), jnp.asarray(live), self.lr,
+            self.phase_steps, self.beta, self.forget, self.win, **step_kw,
+        )
+        self.global_step += 1
+        due = self.cohorts.due_slots(self.global_step)
+        if due is not None:
+            eligible = self._refresh_eligible(jnp.asarray(live))
+            if len(due) < self.max_streams:
+                cohort = np.zeros((self.max_streams,), bool)
+                cohort[due] = True
+                eligible = eligible & jnp.asarray(cohort)
+            rows = jnp.asarray(due, jnp.int32)
+            if self.refresh_mode == "incremental":
+                fn = (_stream_refresh_factor_rows_donated if self.donate
+                      else _stream_refresh_factor_rows)
+                self.states = fn(self.states, eligible, rows)
+            else:
+                fn = (_stream_refresh_rows_donated if self.donate
+                      else _stream_refresh_rows)
+                self.states = fn(self.states, self.beta, eligible, rows)
+        return preds
 
-        # dispatch-time bookkeeping: the slot lifecycle is cursor-driven
-        # (independent of prediction values), so retirement/refill never
-        # waits on the device - only the metric bookkeeping rides the ring.
-        # Meta is sub-step-major, so a blocked step's cursor advances
-        # accumulate in schedule order and a slot retires exactly at its
-        # block's end (the clamp guarantees no earlier completion).
+    def _retire(self, meta: List[Tuple]) -> int:
+        """Advance the cursors and retire every stream that completed;
+        returns how many did.
+
+        Dispatch-time bookkeeping: the slot lifecycle is cursor-driven
+        (independent of prediction values), so retirement/refill never
+        waits on the device - only the metric bookkeeping rides the ring.
+        Meta is sub-step-major, so a blocked step's cursor advances
+        accumulate in schedule order and a slot retires exactly at its
+        block's end (the clamp guarantees no earlier completion)."""
+        retired = 0
         for _t, i, req, lo, n in meta:
             self.slot_pos[i] += n
             if self.slot_pos[i] >= req.n_samples:
-                req.final_state = self._snapshot_row(i)
-                self.sched.retire(i)   # continuous batching: slot refills
-        self._inflight.append((preds, meta))
-        if self._autotuner is not None:
-            self._autotuner.on_step()
-        self.dispatch_times_s.append(time.perf_counter() - t_start)
-        while len(self._inflight) > self.pipeline_depth:
-            self._drain_one()
-        self.step_times_s.append(time.perf_counter() - t_start)
+                with tracing.span("stream.retire", rid=req.rid, slot=i,
+                                  samples=req.n_samples):
+                    req.final_state = self._snapshot_row(i)
+                    self.sched.retire(i)   # continuous batching: refill
+                retired += 1
+        return retired
+
+    def _step_stats(
+        self, first: int, meta: List[Tuple], b_active: int, n_live: int,
+        n_retired: int,
+    ) -> Dict[str, int]:
+        """The ``stream.step`` span's counters, from host values only (the
+        device is never read).  ``refresh_eligible`` counts the due rows
+        the program refreshes: live, with at least ``phase_steps`` windows
+        served before this one, hence past the phase boundary and holding
+        accumulated samples.  ``refresh_rows`` counts the rows its refresh
+        computes, padding included; ``real_timesteps`` the sample steps of
+        the live windows against the ``slot_timesteps`` the kernels run."""
+        rows = eligible = 0
+        for t in range(b_active):
+            c = self.cohorts.due_cohort(first + t)
+            if c is None:
+                continue
+            rows += self._refresh_width(first + t)
+            eligible += sum(
+                1 for tt, i, _req, lo, _n in meta
+                if tt == t and self.cohorts.cohort_of_slot[i] == c
+                and lo // self.window >= self._phase_steps
+            )
+        real = sum(int(req.length[lo:lo + n].sum())
+                   for _t, _i, req, lo, n in meta)
+        return dict(
+            step=self.global_step, live=n_live,
+            admitted=len(self._admitted_this_step), retired=n_retired,
+            refresh_rows=rows, refresh_eligible=eligible,
+            real_timesteps=real,
+            slot_timesteps=self.max_streams * self.window * self.t_max
+            * b_active,
+        )
+
+    def _refresh_width(self, step: int) -> int:
+        """Rows the refresh of due step ``step`` computes."""
+        if self.staging == "host":
+            return len(self.cohorts.due_slots(step))
+        if self.devices > 1:
+            _, rows, _ = self.cohorts.due_rows_fixed_sharded(
+                step, self.devices
+            )
+        else:
+            _, rows, _ = self.cohorts.due_rows_fixed(step)
+        return int(rows.size)
 
     def _step_kw(self) -> Dict:
         return dict(
@@ -1691,20 +1798,21 @@ class StreamServer:
     def _drain_one(self) -> None:
         """Materialize the oldest in-flight step's predictions (the only
         blocking device read) and run its per-sample bookkeeping."""
-        preds, meta = self._inflight.popleft()
-        t0 = time.perf_counter()
-        preds_np = np.asarray(preds)   # blocks: the served predictions
-        self.drain_times_s.append(time.perf_counter() - t0)
-        for t, i, req, lo, n in meta:
-            # blocked steps return (B, S, W); unblocked return (S, W)
-            block = preds_np[t] if preds_np.ndim == 3 else preds_np
-            for k in range(n):
-                pred = int(block[i, k])
-                req.preds.append(pred)
-                req.correct += int(pred == int(req.label[lo + k]))
-            if lo + n >= req.n_samples:
-                req.done = True
-                req.finish_t = time.perf_counter()
+        preds, meta, step = self._inflight.popleft()
+        with tracing.span("stream.drain", step=step):
+            t0 = time.perf_counter()
+            preds_np = np.asarray(preds)   # blocks: the served predictions
+            self.drain_times_s.append(time.perf_counter() - t0)
+            for t, i, req, lo, n in meta:
+                # blocked steps return (B, S, W); unblocked return (S, W)
+                block = preds_np[t] if preds_np.ndim == 3 else preds_np
+                for k in range(n):
+                    pred = int(block[i, k])
+                    req.preds.append(pred)
+                    req.correct += int(pred == int(req.label[lo + k]))
+                if lo + n >= req.n_samples:
+                    req.done = True
+                    req.finish_t = time.perf_counter()
 
     def drain(self) -> None:
         """Synchronize: flush every in-flight pipeline entry (predictions,
@@ -1753,7 +1861,11 @@ class StreamServer:
         return self.sched.completed
 
     def latency_percentiles_ms(self) -> Dict[str, float]:
-        """p50/p99 of the per-step wall time, split honestly for pipelining.
+        """p50/p99 of the step wall time, split honestly for pipelining.
+
+        These are times of ``step()`` calls, not a sample's latency (its
+        wait in the queue and in the ring is not counted); the keys keep
+        their historical names.
 
         ``p50_ms``/``p99_ms``: total wall time of ``step()`` (dispatch plus
         whatever draining that step performed), measured from ``step()``
