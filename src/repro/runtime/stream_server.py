@@ -940,12 +940,40 @@ _pool_write = jax.jit(_pool_write_impl, donate_argnums=(0,))
 
 @jax.jit
 def _snapshot_slot(states: OnlineState, i: Array) -> OnlineState:
-    """Slot row i of the batched state as a single-system state (one
-    dispatch for the whole tree; module-level so servers share the cache).
-    The gather materializes fresh buffers, so the snapshot stays valid
-    after later (donated) steps consume the batched state it was read
-    from - the donation-safety contract of ``StreamRequest.final_state``."""
+    """Slot row i of the batched state as a single-system state: the
+    per-row copy the tests hold ``_snapshot_slots`` to (the server
+    dispatches only the latter)."""
     return jax.tree_util.tree_map(lambda leaf: leaf[i], states)
+
+
+@jax.jit
+def _snapshot_slots(states: OnlineState, idx: Array) -> Tuple[OnlineState, ...]:
+    """Slot rows ``idx`` (K,) of the batched state as K single-system
+    states, in one program, bit for bit: a gather is a selection, not
+    arithmetic, so ``-0.0``, subnormals and NaN payloads survive.  The TPU
+    keeps the ``(S, s, s)`` ridge leaves slot-minor (the slot axis fills
+    the 128-lane tiles, ``s`` does not), so a single-row slice reads the
+    whole leaf; the gather relayouts it once for all K rows.  The K trees
+    leave as separate fresh buffers: no slicing dispatch follows, and each
+    survives later donated steps (the donation-safety contract of
+    ``StreamRequest.final_state``)."""
+    rows = jax.tree_util.tree_map(lambda leaf: jnp.take(leaf, idx, axis=0),
+                                  states)
+    return tuple(jax.tree_util.tree_map(lambda leaf: leaf[k], rows)
+                 for k in range(idx.shape[0]))
+
+
+#: most rows one snapshot program reads: a step that retires more is
+#: split into several programs, so the compiled buckets and their
+#: temporaries stay bounded whatever the slot count
+SNAPSHOT_MAX_ROWS = 32
+
+
+def _snapshot_bucket(k: int, cap: int) -> int:
+    """Rows a batched snapshot of ``k`` slots reads: the next power of
+    two, at most ``cap`` (the slots it reads from), so that only a handful
+    of programs compile."""
+    return min(1 << (k - 1).bit_length(), cap)
 
 
 @partial(jax.jit, static_argnames=())
@@ -1452,21 +1480,53 @@ class StreamServer:
                 self.pool, jnp.asarray(i, jnp.int32), u, length, label, n
             )
 
-    def _snapshot_row(self, i: int) -> OnlineState:
-        """Copy of slot i's state (the retiring stream's final model).  On
-        a slot mesh the copy is read from the owning device's shard:
-        indexing the sharded batch itself gathers every device's (S/n, s, s)
-        block for each retirement."""
-        if self.mesh is None:
-            return _snapshot_slot(self.states, jnp.asarray(i))
-        owner, row = divmod(i, self.states.step.shape[0] // self.devices)
+    def _shard_states(self, owner: int) -> OnlineState:
+        """The batched state's block on device ``owner`` of the slot mesh."""
         dev = self.mesh.devices.flat[owner]
-        local = jax.tree_util.tree_map(
+        return jax.tree_util.tree_map(
             lambda leaf: next(sh.data for sh in leaf.addressable_shards
                               if sh.device == dev),
             self.states,
         )
-        return _snapshot_slot(local, np.int32(row))
+
+    def _snapshot_rows(self, slots: List[int]) -> Tuple[List[OnlineState], int]:
+        """Copies of the slots' states (the retiring streams' final models)
+        and the number of programs dispatched for them.
+
+        ``_snapshot_slots`` reads each owning device's rows, at most
+        ``SNAPSHOT_MAX_ROWS`` per program, with the indices padded to a
+        power-of-two bucket by repeating the first and the padded rows'
+        outputs dropped.  On a slot mesh each owner's rows are read from
+        its own shard: indexing the sharded batch itself gathers every
+        device's (S/n, s, s) block."""
+        if self.mesh is None:
+            groups = [(self.states, list(enumerate(slots)))]
+        else:
+            per = self.states.step.shape[0] // self.devices
+            by_owner: Dict[int, List[Tuple[int, int]]] = {}
+            for n, i in enumerate(slots):
+                owner, row = divmod(i, per)
+                by_owner.setdefault(owner, []).append((n, row))
+            groups = [(self._shard_states(owner), rows)
+                      for owner, rows in by_owner.items()]
+        snaps: List[Optional[OnlineState]] = [None] * len(slots)
+        programs = bucket = 0
+        with tracing.span("stream.snapshot") as span:
+            for states, rows in groups:
+                for lo in range(0, len(rows), SNAPSHOT_MAX_ROWS):
+                    chunk = rows[lo:lo + SNAPSHOT_MAX_ROWS]
+                    idx = np.full(
+                        (_snapshot_bucket(len(chunk), states.step.shape[0]),),
+                        chunk[0][1], np.int32)
+                    idx[:len(chunk)] = [row for _, row in chunk]
+                    got = _snapshot_slots(states, idx)
+                    programs += 1
+                    bucket += len(got)
+                    for (n, _), snap in zip(chunk, got):
+                        snaps[n] = snap
+            if tracing.recording():
+                span.set_metadata(rows=len(slots), bucket=bucket)
+        return snaps, programs
 
     def _cached_mask(self, mask_np: np.ndarray) -> Array:
         """Device copy of a small (S,) bool control mask, cached by value."""
@@ -1546,7 +1606,8 @@ class StreamServer:
         blocking device read), so depth 0 is fully synchronous.
 
         The step is a ``stream.step`` span holding its ``stream.admit``,
-        ``stream.enqueue``, ``stream.retire`` and ``stream.drain`` spans
+        ``stream.enqueue``, ``stream.snapshot``, ``stream.retire`` and
+        ``stream.drain`` spans
         (``repro.runtime.tracing``: recorded only while a profiler runs,
         with the step's counters as stats).
         """
@@ -1560,7 +1621,7 @@ class StreamServer:
                       for t in range(b_active))
             with tracing.span("stream.enqueue", refresh=due):
                 preds = self._enqueue(fresh_mask, live, meta, b_active)
-            retired = self._retire(meta)
+            retired, snapshots = self._retire(meta)
             self._inflight.append((preds, meta, self.global_step))
             if self._autotuner is not None:
                 self._autotuner.on_step()
@@ -1570,7 +1631,8 @@ class StreamServer:
             self.step_times_s.append(time.perf_counter() - t_start)
             if tracing.recording():
                 span.set_metadata(**self._step_stats(
-                    first, meta, b_active, int(live.sum()), retired
+                    first, meta, b_active, int(live.sum()), retired,
+                    snapshots,
                 ))
 
     def _plan_step(self) -> Tuple[np.ndarray, np.ndarray, List[Tuple], int]:
@@ -1683,30 +1745,36 @@ class StreamServer:
                 self.states = fn(self.states, self.beta, eligible, rows)
         return preds
 
-    def _retire(self, meta: List[Tuple]) -> int:
+    def _retire(self, meta: List[Tuple]) -> Tuple[int, int]:
         """Advance the cursors and retire every stream that completed;
-        returns how many did.
+        returns how many did and the snapshot programs dispatched.
 
         Dispatch-time bookkeeping: the slot lifecycle is cursor-driven
         (independent of prediction values), so retirement/refill never
         waits on the device - only the metric bookkeeping rides the ring.
         Meta is sub-step-major, so a blocked step's cursor advances
         accumulate in schedule order and a slot retires exactly at its
-        block's end (the clamp guarantees no earlier completion)."""
-        retired = 0
+        block's end (the clamp guarantees no earlier completion).  The
+        completed streams' final models are snapshot together, after this
+        step's program and before the next admission."""
+        done = []
         for _t, i, req, lo, n in meta:
             self.slot_pos[i] += n
             if self.slot_pos[i] >= req.n_samples:
-                with tracing.span("stream.retire", rid=req.rid, slot=i,
-                                  samples=req.n_samples):
-                    req.final_state = self._snapshot_row(i)
-                    self.sched.retire(i)   # continuous batching: refill
-                retired += 1
-        return retired
+                done.append((i, req))
+        if not done:
+            return 0, 0
+        snaps, programs = self._snapshot_rows([i for i, _ in done])
+        for (i, req), snap in zip(done, snaps):
+            with tracing.span("stream.retire", rid=req.rid, slot=i,
+                              samples=req.n_samples):
+                req.final_state = snap
+                self.sched.retire(i)   # continuous batching: refill
+        return len(done), programs
 
     def _step_stats(
         self, first: int, meta: List[Tuple], b_active: int, n_live: int,
-        n_retired: int,
+        n_retired: int, n_snapshots: int,
     ) -> Dict[str, int]:
         """The ``stream.step`` span's counters, from host values only (the
         device is never read).  ``refresh_eligible`` counts the due rows
@@ -1714,7 +1782,8 @@ class StreamServer:
         served before this one, hence past the phase boundary and holding
         accumulated samples.  ``refresh_rows`` counts the rows its refresh
         computes, padding included; ``real_timesteps`` the sample steps of
-        the live windows against the ``slot_timesteps`` the kernels run."""
+        the live windows against the ``slot_timesteps`` the kernels run;
+        ``snapshot_programs`` the retirement snapshot programs dispatched."""
         rows = eligible = 0
         for t in range(b_active):
             c = self.cohorts.due_cohort(first + t)
@@ -1731,6 +1800,7 @@ class StreamServer:
         return dict(
             step=self.global_step, live=n_live,
             admitted=len(self._admitted_this_step), retired=n_retired,
+            snapshot_programs=n_snapshots,
             refresh_rows=rows, refresh_eligible=eligible,
             real_timesteps=real,
             slot_timesteps=self.max_streams * self.window * self.t_max

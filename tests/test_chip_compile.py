@@ -1,24 +1,29 @@
-"""Compile-only tests of the main-path kernels for a described TPU v5e.
+"""Compile-only tests of the main-path programs for a described TPU v5e.
 
 The chip's compiler is installed beside JAX, and it compiles for a v5e
 that is described rather than attached: nothing runs, but everything the
 chip's Mosaic lowering refuses (a block shape off the (8, 128) tiling, too
-much VMEM) is refused here.  Every test compiles one wrapper of
-``kernels.ops`` at the paper's Nx = 30 and checks that the program holds
-the Pallas kernel (``tpu_custom_call``).
+much VMEM) is refused here.  The kernel tests compile one wrapper of
+``kernels.ops`` at the paper's Nx = 30 and check that the program holds
+the Pallas kernel (``tpu_custom_call``); the snapshot test holds the
+stream server's batched retirement snapshot to the compiler's estimates.
 
 The topology is described inside a module-scoped fixture, never while a
 module is imported: only one process at a time may load the TPU library,
 and the test workers must all collect the same tests.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core.online import init_state
+from repro.core.types import DFRConfig
 from repro.kernels import ops
+from repro.runtime import stream_server as ss
 
 NX, NY, T_MAX, N_IN = 30, 10, 93, 13     # ARAB at the paper's width
 NR = NX * NX + NX
@@ -102,3 +107,33 @@ def test_train_forward_compiles(spec, members):
         spec((256, T_MAX, NX)), spec((256,), jnp.int32), spec((members,)),
         spec((members,)),
     )
+
+
+def _cost(fn, *args):
+    """(summed ``estimated_cycles`` of the compiled program, its
+    temporary bytes) for the described chip."""
+    compiled = fn.lower(*args).compile()
+    cycles = sum(int(c) for c in re.findall(
+        r'"estimated_cycles":"?(\d+)', compiled.as_text()))
+    return cycles, compiled.memory_analysis().temp_size_in_bytes
+
+
+def test_batched_snapshot_reads_each_leaf_once(spec):
+    """The stream server's retirement snapshot of K = 32 rows (the most
+    one program reads) of the 512-slot state at Nx = 30 (s = 931)
+    compiles, and costs what a few single-row snapshots cost, not K of
+    them: the TPU keeps the (512, 931, 931) leaves slot-minor, so each
+    per-row slice reads a whole leaf through a 446 MB padded temporary,
+    while the gather relayouts each leaf once for all K rows."""
+    cfg = DFRConfig(n_in=N_IN, n_classes=NY, n_nodes=NX)
+    slots, k = 512, ss.SNAPSHOT_MAX_ROWS
+    states = jax.tree_util.tree_map(
+        lambda leaf: spec((slots, *leaf.shape), leaf.dtype),
+        jax.eval_shape(lambda: init_state(cfg)))
+    one_cycles, one_temp = _cost(ss._snapshot_slot, states,
+                                 spec((), jnp.int32))
+    cycles, temp = _cost(ss._snapshot_slots, states, spec((k,), jnp.int32))
+    assert one_cycles > 0 and cycles > 0
+    # K single-row slices in one program: 32x the cycles, 18x the bytes
+    assert cycles < 8 * one_cycles
+    assert temp < 8 * one_temp

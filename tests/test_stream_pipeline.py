@@ -7,9 +7,12 @@ The contracts under test (see runtime/stream_server.py module docstring):
     retire episode, in every retirement mode;
   * async pipelining (depth 1/2, donated) is bit-for-bit the synchronous
     depth-0 schedule (the lag only defers metric bookkeeping);
-  * buffer donation never changes numerics, and the retirement snapshot
-    (``_snapshot_row``) stays valid after later donated steps consume the
-    batched state it was gathered from (no use-after-donate);
+  * buffer donation never changes numerics, and the retirement snapshots
+    (``_snapshot_rows``, one or several rows a program) stay valid after
+    later donated steps consume the batched state they were read from (no
+    use-after-donate);
+  * the batched snapshot of the streams that retire in one step is bit
+    for bit the single-slot snapshot of each, padded rows dropped;
   * ``cfg.dtype`` is honored end to end (the PR-4 host staging hardcoded
     float32, silently upcasting bf16 configs);
   * ``run_until_drained(max_steps)`` truncation is never silent;
@@ -24,8 +27,10 @@ import jax
 import jax.numpy as jnp
 import pytest
 
+from repro.core.online import init_state
 from repro.core.types import DFRConfig
 from repro.runtime import StreamRequest, StreamServer
+from repro.runtime import stream_server as ss
 
 
 CFG = DFRConfig(n_in=2, n_classes=3, n_nodes=8)
@@ -165,23 +170,167 @@ def test_donation_preserves_numerics_and_snapshots():
             assert np.all(np.isfinite(np.asarray(leaf, np.float64)))
 
 
-def test_snapshot_survives_interleaved_donated_steps():
-    """Direct use-after-donate probe: snapshot a live slot mid-episode,
-    run more donated steps, then read the snapshot - its buffers must be
-    independent of the donated state tree."""
+@pytest.mark.parametrize("slots", [[0], [1, 0]], ids=["one", "batched"])
+def test_snapshot_survives_interleaved_donated_steps(slots):
+    """Direct use-after-donate probe: snapshot live slots mid-episode (one
+    row or two, one batched program either way), run more donated steps,
+    then read the snapshots - their buffers must be independent of the
+    donated state tree."""
     srv = StreamServer(CFG, t_max=16, max_streams=2, window=2,
                        phase_steps=1, refresh_every=2, donate=True)
     for s in _episode_streams():
         srv.submit(s)
     for _ in range(3):
         srv.step()
-    snap = srv._snapshot_row(0)
-    ref = [np.asarray(leaf).copy() for leaf in jax.tree_util.tree_leaves(snap)]
+    snaps, programs = srv._snapshot_rows(slots)
+    assert programs == 1 and len(snaps) == len(slots)
+    ref = [[np.asarray(leaf).copy() for leaf in jax.tree_util.tree_leaves(sn)]
+           for sn in snaps]
     for _ in range(4):
         srv.step()           # donated dispatches consume srv.states
     srv.drain()
-    for leaf, r in zip(jax.tree_util.tree_leaves(snap), ref):
-        np.testing.assert_array_equal(np.asarray(leaf), r)
+    for sn, want in zip(snaps, ref):
+        for leaf, r in zip(jax.tree_util.tree_leaves(sn), want):
+            np.testing.assert_array_equal(np.asarray(leaf), r)
+
+
+# ---------------------------------------------------------------------------
+# Batched retirement snapshots == single-slot snapshots, bit for bit
+# ---------------------------------------------------------------------------
+
+
+def _bits(tree):
+    """Each leaf's raw bytes: -0.0, NaN payloads and subnormals count."""
+    return [np.asarray(leaf).reshape(-1).view(np.uint8)
+            for leaf in jax.tree_util.tree_leaves(tree)]
+
+
+def _assert_same_bits(sa, sb):
+    for a, b in zip(_bits(sa), _bits(sb)):
+        np.testing.assert_array_equal(a, b)
+
+
+class _CheckedServer(StreamServer):
+    """Records, beside every batch of retirement snapshots, the slots and
+    the single-slot snapshot of each taken from the same batched state."""
+
+    def __init__(self, *a, **kw):
+        super().__init__(*a, **kw)
+        self.snapshot_log = []
+
+    def _snapshot_rows(self, slots):
+        want = [ss._snapshot_slot(self.states, np.int32(i)) for i in slots]
+        snaps, programs = super()._snapshot_rows(slots)
+        self.snapshot_log.append((list(slots), snaps, want, programs))
+        return snaps, programs
+
+
+@pytest.mark.parametrize("kind,kw", [
+    ("device", {}), ("host", {"staging": "host"}),
+    ("blocked", {"step_block": 2}),
+], ids=["device", "host", "blocked"])
+@pytest.mark.parametrize("k", [1, 3, 8])
+def test_batched_snapshots_are_bitwise_the_single_slot(k, kind, kw):
+    """K streams that complete in the same step retire through one
+    snapshot program, and each final model is bit for bit the single-slot
+    snapshot of its row at that point; the other streams retire in steps
+    of their own."""
+    lengths = [4] * k + [6, 8, 10][:max(0, 8 - k)]
+    streams = [_make_stream(i, n, seed=20 + i) for i, n in enumerate(lengths)]
+    srv = _CheckedServer(CFG, t_max=16, max_streams=8, window=2,
+                         phase_steps=1, refresh_every=2, **kw)
+    for s in streams:
+        srv.submit(s)
+    done = srv.run_until_drained()
+    assert len(done) == len(streams)
+    assert [len(slots) for slots, *_ in srv.snapshot_log][0] == k
+    for slots, snaps, want, programs in srv.snapshot_log:
+        assert programs == 1
+        assert len(snaps) == len(want) == len(slots)
+        for got, ref in zip(snaps, want):
+            _assert_same_bits(got, ref)
+    # every final model is one of those snapshots, each stream its own
+    snapped = [id(sn) for _, snaps, _, _ in srv.snapshot_log for sn in snaps]
+    assert sorted(id(r.final_state) for r in done) == sorted(snapped)
+
+
+def test_padded_bucket_rows_never_reach_a_final_state():
+    """Three rows gather as a bucket of four (the first row repeated);
+    only the three requested snapshots come back, each its own row."""
+    srv = StreamServer(CFG, t_max=16, max_streams=4, window=2,
+                       phase_steps=1, refresh_every=2)
+    for s in [_make_stream(i, 12, seed=40 + i) for i in range(4)]:
+        srv.submit(s)
+    for _ in range(3):
+        srv.step()
+    assert ss._snapshot_bucket(3, 4) == 4
+    slots = [2, 0, 3]
+    snaps, programs = srv._snapshot_rows(slots)
+    assert programs == 1 and len(snaps) == 3
+    for i, got in zip(slots, snaps):
+        _assert_same_bits(got, ss._snapshot_slot(srv.states, np.int32(i)))
+    # slot 2 (the pad) differs from the others, so a leaked pad would show
+    for got in snaps[1:]:
+        assert any(not np.array_equal(a, b) for a, b in
+                   zip(_bits(got), _bits(snaps[0])))
+    ids = [id(leaf) for sn in snaps for leaf in jax.tree_util.tree_leaves(sn)]
+    assert len(set(ids)) == len(ids)
+
+
+def test_snapshot_programs_read_a_bounded_number_of_rows(monkeypatch):
+    """A step that retires more rows than one program reads splits them
+    into programs of at most ``SNAPSHOT_MAX_ROWS`` (each padded to its own
+    bucket), so no larger bucket ever compiles."""
+    monkeypatch.setattr(ss, "SNAPSHOT_MAX_ROWS", 2)
+    buckets = []
+    real = ss._snapshot_slots
+
+    def counted(states, idx):
+        buckets.append(idx.shape[0])
+        return real(states, idx)
+
+    monkeypatch.setattr(ss, "_snapshot_slots", counted)
+    srv = StreamServer(CFG, t_max=16, max_streams=8, window=2,
+                       phase_steps=1, refresh_every=2)
+    for s in [_make_stream(i, 12, seed=60 + i) for i in range(8)]:
+        srv.submit(s)
+    for _ in range(3):
+        srv.step()
+    slots = [5, 1, 7, 2, 6]
+    snaps, programs = srv._snapshot_rows(slots)
+    assert programs == 3 and buckets == [2, 2, 1]
+    for i, got in zip(slots, snaps):
+        _assert_same_bits(got, ss._snapshot_slot(srv.states, np.int32(i)))
+
+
+@pytest.mark.parametrize("k", [1, 3, 8])
+def test_batched_snapshot_keeps_every_bit(k):
+    """On arbitrary bit patterns - -0.0, subnormals, infinities, NaNs with
+    payloads, planted in every row - the batched program copies the rows
+    exactly: it is a selection, not arithmetic."""
+    rng = np.random.default_rng(k)
+    single = jax.eval_shape(lambda: init_state(CFG, factor_beta=0.01))
+
+    def leaf(sd):
+        raw = rng.integers(0, 2**32, size=(8, sd.size), dtype=np.uint64)
+        raw = raw.astype(np.uint32)
+        raw[:, :6] = [0x80000000, 0x00000001, 0x807FFFFF, 0x7FC00123,
+                      0xFF800000, 0xFFFFFFFF][:sd.size]
+        dtype = np.dtype(sd.dtype)
+        if dtype.itemsize == 4:
+            return raw.view(dtype).reshape((8, *sd.shape))
+        return raw.astype(dtype).reshape((8, *sd.shape))
+
+    host = jax.tree_util.tree_map(leaf, single)
+    states = jax.tree_util.tree_map(jnp.asarray, host)
+    rows = rng.choice(8, size=k, replace=False).astype(np.int32)
+    width = ss._snapshot_bucket(k, 8)
+    idx = np.full((width,), rows[0], np.int32)
+    idx[:k] = rows
+    got = ss._snapshot_slots(states, idx)
+    assert len(got) == width
+    for i, sn in zip(rows, got):
+        _assert_same_bits(sn, jax.tree_util.tree_map(lambda h: h[i], host))
 
 
 # ---------------------------------------------------------------------------

@@ -214,6 +214,63 @@ def test_sharded_step_program_has_no_collective():
     assert collectives_in(srv.step_program_text()) == []
 
 
+class _SnapshotLog(StreamServer):
+    """Records each batch of retirement snapshots with the rows of the
+    whole batched state they must copy (gathered to the host)."""
+
+    def __init__(self, *a, **kw):
+        super().__init__(*a, **kw)
+        self.snapshot_log = []
+
+    def _snapshot_rows(self, slots):
+        rows = [jax.tree_util.tree_map(lambda leaf: np.asarray(leaf)[i],
+                                       self.states) for i in slots]
+        snaps, programs = super()._snapshot_rows(slots)
+        self.snapshot_log.append((list(slots), snaps, rows, programs))
+        return snaps, programs
+
+
+def _same_bits(a, b):
+    return all(
+        np.array_equal(np.asarray(x).reshape(-1).view(np.uint8),
+                       np.asarray(y).reshape(-1).view(np.uint8))
+        for x, y in zip(jax.tree_util.tree_leaves(a),
+                        jax.tree_util.tree_leaves(b)))
+
+
+@needs_devices
+@pytest.mark.parametrize("devices", [2, 4])
+def test_sharded_snapshots_come_from_the_owner_shard(devices):
+    """Each retiring stream's snapshot is read on the device that owns its
+    slot, by one program per owner with retirements in the step, and is
+    bit for bit its row of the batched state; the unsharded server's final
+    model of the same stream matches it under the parity rule (the sharded
+    step program itself may differ in the last ulps)."""
+    srv = _SnapshotLog(CFG, t_max=10, max_streams=8, window=2,
+                       phase_steps=3, refresh_every=4, devices=devices,
+                       refresh_mode="incremental")
+    for s in _episode_streams():
+        srv.submit(s)
+    done = srv.run_until_drained()
+    per = 8 // devices
+    multi = 0
+    for slots, snaps, rows, programs in srv.snapshot_log:
+        owners = {i // per for i in slots}
+        assert programs == len(owners)
+        multi += len(owners) > 1
+        for i, snap, row in zip(slots, snaps, rows):
+            owner = srv.mesh.devices.flat[i // per]
+            for leaf in jax.tree_util.tree_leaves(snap):
+                assert leaf.devices() == {owner}
+            assert _same_bits(snap, row)
+    assert multi, "no step retired streams of several owners"
+    _, srv_1 = _baseline("none", RETIREMENT_MODES[0][1])
+    want = {r.rid: r.final_state for r in srv_1.completed}
+    assert len(done) == len(want)
+    for r in done:
+        assert_parity(want[r.rid], r.final_state)
+
+
 # ---------------------------------------------------------------------------
 # Placement: the device-local invariant, structurally
 # ---------------------------------------------------------------------------

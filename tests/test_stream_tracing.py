@@ -147,7 +147,8 @@ def test_span_counts(recorded, kind):
 def test_admit_enqueue_retire_lie_inside_their_step(recorded, kind):
     spans = recorded(kind)[0]
     steps = _named(spans, "stream.step")
-    for name in ("stream.admit", "stream.enqueue", "stream.retire"):
+    for name in ("stream.admit", "stream.enqueue", "stream.snapshot",
+                 "stream.retire"):
         for s in _named(spans, name):
             assert sum(_inside(st, s) for st in steps) == 1, (name, s)
     # stage spans come from submit(), outside every step
@@ -184,9 +185,13 @@ def test_step_counters(recorded, kind):
     stats = [s[3] for s in _named(spans, "stream.step")]
     admits = _per_step(spans, "stream.admit")
     retires = _per_step(spans, "stream.retire")
+    snapshots = _per_step(spans, "stream.snapshot")
     for k, st in enumerate(stats):
         assert st["admitted"] == len(admits[k])
         assert st["retired"] == len(retires[k])
+        # one device: a step that retires dispatches one snapshot program
+        assert st["snapshot_programs"] == len(snapshots[k]) == (
+            1 if retires[k] else 0)
         assert st["live"] == len(snaps[k][0]) + st["retired"]
         assert 0 < st["real_timesteps"] <= st["slot_timesteps"]
         assert st["slot_timesteps"] % (SLOTS * WINDOW * T_MAX) == 0
@@ -201,6 +206,32 @@ def test_step_counters(recorded, kind):
     # drains name the step they read, each step once
     assert sorted(s[3]["step"] for s in _named(spans, "stream.drain")) == [
         st["step"] for st in stats]
+
+
+@pytest.mark.parametrize("kind", sorted(KINDS))
+def test_snapshot_span_batches_the_steps_retirements(recorded, kind):
+    """Once per step that retires: ``rows`` is that step's retirements,
+    ``bucket`` the rows read (padding included, a power of two up to the
+    slots).  The snapshot precedes the step's per-stream ``stream.retire``
+    bookkeeping."""
+    spans = recorded(kind)[0]
+    retires = _per_step(spans, "stream.retire")
+    snapshots = _per_step(spans, "stream.snapshot")
+    n_steps = 0
+    for rets, snaps in zip(retires, snapshots):
+        if not rets:
+            assert snaps == []
+            continue
+        n_steps += 1
+        (snap,) = snaps
+        rows, bucket = snap[3]["rows"], snap[3]["bucket"]
+        assert rows == len(rets)
+        assert bucket == ss._snapshot_bucket(rows, SLOTS) >= rows
+        assert all(snap[2] <= r[1] for r in rets)
+    assert n_steps == len(_named(spans, "stream.snapshot")) > 0
+    # the episode retires one stream alone and several together
+    rows = [s[3]["rows"] for s in _named(spans, "stream.snapshot")]
+    assert min(rows) == 1 and max(rows) > 1
 
 
 @pytest.mark.parametrize("kind", ["pipelined", "host"])
