@@ -303,11 +303,12 @@ def _fused_features_bwd(spec, res, cts):
         *dr.shape[:-1], n_nodes, n_nodes
     )
     dr_sum = dr[..., n_nodes * n_nodes:]
-    bpv = jnp.einsum("...nj,...j->...n", dr_outer, x_prev) + dr_sum
+    bpv = jnp.einsum("...nj,...j->...n", dr_outer, x_prev,
+                     precision=DOT_PRECISION) + dr_sum
 
     # Eq. 34: reversed ring recurrence, closed form via L(q)
     Lq = res_mod.ring_matrix(q, n_nodes, bpv.dtype)
-    dx = jnp.einsum("nm,...n->...m", Lq, bpv)
+    dx = jnp.einsum("nm,...n->...m", Lq, bpv, precision=DOT_PRECISION)
 
     # Eq. 35 / Eq. 36
     f_T = spec.f(j_last + x_prev)

@@ -10,7 +10,9 @@ program:
   1. ``grid_candidates``       - grid-seeded (p, q) starts (the paper's own
                                  log-space search box, Sec. 4.1).
   2. ``evaluate_population``   - one jitted program: vmapped reservoir+DPRR
-                                 features, population-axis sufficient
+                                 features (the fused training forward's r,
+                                 ``kernels.ops.train_forward``),
+                                 population-axis sufficient
                                  statistics A (K, Ny, s) / B (K, s, s),
                                  batched packed ridge solves over the beta
                                  sweep (``ridge.ridge_solve_batched``; the
@@ -44,6 +46,12 @@ Shapes: every population tensor carries a leading K axis; ``DFRParams`` is
 reused as the population pytree with leaves p (K,), q (K,), W (K, Ny, Nr),
 b (K, Ny).  Memory in ``evaluate_population`` scales as K * B * s for the
 feature matrices - size the population to the accelerator accordingly.
+
+The round driver records host spans on the profiler's clock
+(``repro.runtime.tracing``): ``search.evaluate``, ``search.select``,
+``search.refine`` and ``search.round``; the programs carry the op scopes
+``search.features``, ``search.gram``, ``search.solve``, ``search.predict``
+and ``search.sgd``.
 """
 from __future__ import annotations
 
@@ -56,7 +64,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import backprop, dprr, masking, reservoir, ridge
+from repro.core import backprop, dprr, masking, ridge
 from repro.core.candidates import (  # noqa: F401  (shared candidate machinery,
     P_LOG_RANGE,                     # re-exported for compatibility - the
     Q_LOG_RANGE,                     # online ensemble imports the same
@@ -73,6 +81,8 @@ from repro.core.types import (
     RegressionBatch,
     TimeSeriesBatch,
 )
+from repro.kernels import ops as kops
+from repro.runtime import tracing
 
 
 # ---------------------------------------------------------------------------
@@ -130,13 +140,16 @@ def evaluate_population(
     f = cfg.f()
 
     def feats(p, q, u, lengths):
+        # the fused training forward's r: the state sequence is never
+        # materialized (K x B x T x Nx floats would not fit the device at
+        # the paper's Table-4 lengths)
         j_seq = masking.apply_mask(mask, u)
-        x = reservoir.run_reservoir(p, q, j_seq, f=f, lengths=lengths)
-        return dprr.compute_dprr(x, lengths=lengths)
+        return kops.train_forward(j_seq, lengths, p, q, cfg.n_nodes, f=f)[0]
 
     vfeats = jax.vmap(feats, in_axes=(0, 0, None, None))
-    rt_train = dprr.r_tilde(vfeats(ps, qs, train_u, train_len))  # (K, B, s)
-    rt_eval = dprr.r_tilde(vfeats(ps, qs, eval_u, eval_len))     # (K, Be, s)
+    with jax.named_scope("search.features"):
+        rt_train = dprr.r_tilde(vfeats(ps, qs, train_u, train_len))  # (K, B, s)
+        rt_eval = dprr.r_tilde(vfeats(ps, qs, eval_u, eval_len))     # (K, Be, s)
 
     k = rt_train.shape[0]
     n_train, s = rt_train.shape[1], rt_train.shape[2]
@@ -146,40 +159,47 @@ def evaluate_population(
 
     if use_dual:
         # one factorization for the whole (beta, member) sweep
-        Kmat = jnp.einsum("kbs,kcs->kbc", rt_train, rt_train,
-                          precision=DOT_PRECISION)       # (K, B, B)
-        eye = jnp.eye(n_train, dtype=Kmat.dtype)
-        G = Kmat[None] + betas[:, None, None, None] * eye        # (nb, K, B, B)
-        C = jnp.linalg.cholesky(G.reshape(n_beta * k, n_train, n_train))
-        y_b = jnp.broadcast_to(y_train, (n_beta * k, *y_train.shape))
-        X = jax.vmap(
-            lambda c, y: jax.scipy.linalg.cho_solve((c, True), y)
-        )(C, y_b).reshape(n_beta, k, n_train, -1)
-        Wt_all = jnp.einsum("nkby,kbs->nkys", X, rt_train,
-                            precision=DOT_PRECISION)     # (nb, K, Ny, s)
+        with jax.named_scope("search.gram"):
+            Kmat = jnp.einsum("kbs,kcs->kbc", rt_train, rt_train,
+                              precision=DOT_PRECISION)   # (K, B, B)
+        with jax.named_scope("search.solve"):
+            eye = jnp.eye(n_train, dtype=Kmat.dtype)
+            G = Kmat[None] + betas[:, None, None, None] * eye    # (nb, K, B, B)
+            C = jnp.linalg.cholesky(G.reshape(n_beta * k, n_train, n_train))
+            y_b = jnp.broadcast_to(y_train, (n_beta * k, *y_train.shape))
+            X = jax.vmap(
+                lambda c, y: jax.scipy.linalg.cho_solve((c, True), y)
+            )(C, y_b).reshape(n_beta, k, n_train, -1)
+            Wt_all = jnp.einsum("nkby,kbs->nkys", X, rt_train,
+                                precision=DOT_PRECISION)  # (nb, K, Ny, s)
     else:
-        A = jnp.einsum("by,kbs->kys", y_train, rt_train,
-                       precision=DOT_PRECISION)
-        Bmat = jnp.einsum("kbs,kbt->kst", rt_train, rt_train,
-                          precision=DOT_PRECISION)
-        Wt_all = jnp.stack([
-            ridge.ridge_solve_batched(
-                A, ridge.regularize(Bmat, beta.astype(Bmat.dtype)), ridge_method
-            )
-            for beta in betas
-        ])
+        with jax.named_scope("search.gram"):
+            A = jnp.einsum("by,kbs->kys", y_train, rt_train,
+                           precision=DOT_PRECISION)
+            Bmat = jnp.einsum("kbs,kbt->kst", rt_train, rt_train,
+                              precision=DOT_PRECISION)
+        with jax.named_scope("search.solve"):
+            Wt_all = jnp.stack([
+                ridge.ridge_solve_batched(
+                    A, ridge.regularize(Bmat, beta.astype(Bmat.dtype)),
+                    ridge_method,
+                )
+                for beta in betas
+            ])
 
-    pred = jnp.einsum("kbs,nkys->nkby", rt_eval, Wt_all,
-                      precision=DOT_PRECISION)           # (nb, K, Be, Ny)
-    var = jnp.mean(jnp.square(y_eval - jnp.mean(y_eval))) + 1e-12
-    err = pred - y_eval[None, None]
-    nrmse = jnp.sqrt(jnp.mean(err * err, axis=(2, 3)) / var)     # (nb, K)
-    nrmse = jnp.where(jnp.isfinite(nrmse), nrmse, jnp.inf)
-    labels_eval = jnp.argmax(y_eval, axis=-1)
-    acc = jnp.mean(
-        (jnp.argmax(pred, -1) == labels_eval[None, None]).astype(jnp.float32),
-        axis=2,
-    )                                                            # (nb, K)
+    with jax.named_scope("search.predict"):
+        pred = jnp.einsum("kbs,nkys->nkby", rt_eval, Wt_all,
+                          precision=DOT_PRECISION)       # (nb, K, Be, Ny)
+        var = jnp.mean(jnp.square(y_eval - jnp.mean(y_eval))) + 1e-12
+        err = pred - y_eval[None, None]
+        nrmse = jnp.sqrt(jnp.mean(err * err, axis=(2, 3)) / var)  # (nb, K)
+        nrmse = jnp.where(jnp.isfinite(nrmse), nrmse, jnp.inf)
+        labels_eval = jnp.argmax(y_eval, axis=-1)
+        acc = jnp.mean(
+            (jnp.argmax(pred, -1) == labels_eval[None, None]).astype(
+                jnp.float32),
+            axis=2,
+        )                                                        # (nb, K)
 
     # argmax/argmin keep the earliest beta on ties, matching the serial grid
     # search's argmax semantics over the beta sweep
@@ -244,13 +264,14 @@ def refine_population(
     def member(params_k: DFRParams):
         def sgd_step(params, inp):
             ub, lb, yb = inp
-            j_seq = masking.apply_mask(mask, ub)
-            l, g = grads(
-                params, j_seq, yb, f, lengths=lb, loss_fn=loss_fn
-            )
-            new = backprop.apply_sgd(
-                params, g, lr_res, lr_out, inv_batch=1.0 / mb
-            )
+            with jax.named_scope("search.sgd"):
+                j_seq = masking.apply_mask(mask, ub)
+                l, g = grads(
+                    params, j_seq, yb, f, lengths=lb, loss_fn=loss_fn
+                )
+                new = backprop.apply_sgd(
+                    params, g, lr_res, lr_out, inv_batch=1.0 / mb
+                )
             return new, l / mb
 
         def epoch(params, _):
@@ -283,6 +304,9 @@ class PopulationResult:
     population: DFRParams   # final stacked population
     final_eval: PopulationEval
     time_s: float
+    # the population that entered the last refinement (after its cull);
+    # None when no round ran
+    refined_from: Optional[DFRParams]
 
 
 def _load_readout(pop: DFRParams, Wt: Array) -> DFRParams:
@@ -361,45 +385,71 @@ def train_population(
     t0 = time.perf_counter()
     ps, qs = grid_candidates(divs, p_range, q_range, cfg.dtype)
     pop = init_population(cfg, ps, qs)
+    k = int(pop.p.shape[0])
     key = jax.random.PRNGKey(seed)
+    mb = min(minibatch, train_u.shape[0])
+    n_sgd = train_u.shape[0] // mb * mb
 
     def ev_pop(pop):
-        return evaluate_population(
-            cfg, mask, pop.p, pop.q, train_u, train_len, y_train,
-            eval_u, eval_len, y_eval, select=select, solver=solver,
-        )
+        # enqueue the evaluation and read what the ranking needs
+        with tracing.span("search.evaluate") as sp:
+            ev = evaluate_population(
+                cfg, mask, pop.p, pop.q, train_u, train_len, y_train,
+                eval_u, eval_len, y_eval, select=select, solver=solver,
+            )
+            jax.block_until_ready((ev.nrmse, ev.acc, ev.beta_idx))
+            if tracing.recording():
+                sp.set_metadata(**_timestep_stats(
+                    k, [train_len, eval_len],
+                    [train_u.shape[1], eval_u.shape[1]]))
+        return ev
 
+    refined_from = None
     ev = ev_pop(pop)
-    elite = _best_member(pop, ev, cfg, select)
+    with tracing.span("search.select"):
+        elite = _best_member(pop, ev, cfg, select)
     history = [{
         "round": 0, "best_nrmse": elite["nrmse"], "best_acc": elite["acc"],
         "mean_nrmse": float(np.mean(np.asarray(ev.nrmse))), "refine_loss": None,
     }]
 
     for r in range(rounds):
-        fitness = -ev.acc if select == "acc" else ev.nrmse
-        key, kc = jax.random.split(key)
-        pop = cull_population(
-            _load_readout(pop, ev.Wt), fitness, kc,
-            survive_frac=survive_frac, jitter=jitter,
-            p_range=p_range, q_range=q_range,
-        )
-        lr_r = jnp.asarray(lr * (0.1 ** r), cfg.dtype)
-        pop, losses = refine_population(
-            cfg, mask, pop, train_u, train_len, y_train, lr_r, lr_r,
-            steps=steps_per_round, minibatch=minibatch, loss=loss,
-            fused=fused,
-        )
-        ev = ev_pop(pop)
-        cand = _best_member(pop, ev, cfg, select)
-        if cand["metric"] > elite["metric"]:
-            elite = cand
-        history.append({
-            "round": r + 1, "best_nrmse": elite["nrmse"],
-            "best_acc": elite["acc"],
-            "mean_nrmse": float(np.mean(np.asarray(ev.nrmse))),
-            "refine_loss": float(np.mean(np.asarray(losses))),
-        })
+        with tracing.span("search.round", round=r + 1, members=k):
+            with tracing.span("search.select"):
+                fitness = -ev.acc if select == "acc" else ev.nrmse
+                key, kc = jax.random.split(key)
+                pop = cull_population(
+                    _load_readout(pop, ev.Wt), fitness, kc,
+                    survive_frac=survive_frac, jitter=jitter,
+                    p_range=p_range, q_range=q_range,
+                )
+            refined_from = pop
+            lr_r = jnp.asarray(lr * (0.1 ** r), cfg.dtype)
+            with tracing.span("search.refine") as sp:
+                pop, losses = refine_population(
+                    cfg, mask, pop, train_u, train_len, y_train, lr_r, lr_r,
+                    steps=steps_per_round, minibatch=minibatch, loss=loss,
+                    fused=fused,
+                )
+                refine_loss = float(np.mean(np.asarray(losses)))
+                if tracing.recording():
+                    sp.set_metadata(
+                        sgd_steps=steps_per_round * (n_sgd // mb),
+                        **_timestep_stats(
+                            k * steps_per_round,
+                            [np.asarray(train_len)[:n_sgd]],
+                            [train_u.shape[1]]))
+            ev = ev_pop(pop)
+            with tracing.span("search.select"):
+                cand = _best_member(pop, ev, cfg, select)
+                if cand["metric"] > elite["metric"]:
+                    elite = cand
+            history.append({
+                "round": r + 1, "best_nrmse": elite["nrmse"],
+                "best_acc": elite["acc"],
+                "mean_nrmse": float(np.mean(np.asarray(ev.nrmse))),
+                "refine_loss": refine_loss,
+            })
 
     return PopulationResult(
         best_params=elite["params"],
@@ -412,7 +462,23 @@ def train_population(
         population=pop,
         final_eval=ev,
         time_s=time.perf_counter() - t0,
+        refined_from=refined_from,
     )
+
+
+def _timestep_stats(members: int, lengths: List[Array],
+                    t_max: List[int]) -> dict:
+    """Span stats of a pass of ``members`` over the splits with these
+    lengths, padded to ``t_max`` steps: member-samples, the real time steps
+    and the time steps the arrays hold.  Read on the host: a device op here
+    would compile inside a traced window."""
+    lens = [np.asarray(x) for x in lengths]
+    return {
+        "samples": members * sum(x.size for x in lens),
+        "real_timesteps": members * sum(int(x.sum()) for x in lens),
+        "padded_timesteps": members * sum(
+            x.size * t for x, t in zip(lens, t_max)),
+    }
 
 
 # ---------------------------------------------------------------------------

@@ -137,3 +137,38 @@ def test_batched_snapshot_reads_each_leaf_once(spec):
     # K single-row slices in one program: 32x the cycles, 18x the bytes
     assert cycles < 8 * one_cycles
     assert temp < 8 * one_temp
+
+
+@pytest.mark.parametrize("program", ["evaluate", "refine"])
+def test_search_programs_fit_one_chip(spec, program, monkeypatch):
+    """The population search on NET at the paper's Nx = 30 (K = 64
+    members, 803 train and 534 eval samples of T up to 994, minibatch 8)
+    compiles with the training kernel in both programs and fits one v5e's
+    HBM: evaluation features through the kernel hold no state sequence
+    (run_reservoir's would need about 20 GB of temporaries)."""
+    from repro.core import population
+    from repro.core.types import DFRParams
+
+    monkeypatch.setattr(ops, "_auto_backend",
+                        lambda b: "tpu" if b is None else b)
+    k, b_tr, b_ev, t, n_in, ny = 64, 803, 534, 994, 4, 13
+    cfg = DFRConfig(n_in=n_in, n_classes=ny, n_nodes=NX)
+    mask = spec((NX, n_in))
+    if program == "evaluate":
+        lowered = population.evaluate_population.lower(
+            cfg, mask, spec((k,)), spec((k,)), spec((b_tr, t, n_in)),
+            spec((b_tr,), jnp.int32), spec((b_tr, ny)),
+            spec((b_ev, t, n_in)), spec((b_ev,), jnp.int32),
+            spec((b_ev, ny)), select="acc")
+    else:
+        pop = DFRParams(p=spec((k,)), q=spec((k,)), W=spec((k, ny, NR)),
+                        b=spec((k, ny)))
+        lowered = population.refine_population.lower(
+            cfg, mask, pop, spec((b_tr, t, n_in)), spec((b_tr,), jnp.int32),
+            spec((b_tr, ny)), spec(()), spec(()), minibatch=8)
+    compiled = lowered.compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes)
+    assert used < 12 * 2 ** 30, used
