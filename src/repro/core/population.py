@@ -433,12 +433,12 @@ def train_population(
                 )
                 refine_loss = float(np.mean(np.asarray(losses)))
                 if tracing.recording():
+                    batches = np.asarray(train_len)[:n_sgd].reshape(-1, mb)
                     sp.set_metadata(
                         sgd_steps=steps_per_round * (n_sgd // mb),
                         **_timestep_stats(
-                            k * steps_per_round,
-                            [np.asarray(train_len)[:n_sgd]],
-                            [train_u.shape[1]]))
+                            k * steps_per_round, list(batches),
+                            [train_u.shape[1]] * len(batches)))
             ev = ev_pop(pop)
             with tracing.span("search.select"):
                 cand = _best_member(pop, ev, cfg, select)
@@ -468,16 +468,20 @@ def train_population(
 
 def _timestep_stats(members: int, lengths: List[Array],
                     t_max: List[int]) -> dict:
-    """Span stats of a pass of ``members`` over the splits with these
-    lengths, padded to ``t_max`` steps: member-samples, the real time steps
-    and the time steps the arrays hold.  Read on the host: a device op here
-    would compile inside a traced window."""
+    """Span stats of a pass of ``members`` over batches with these
+    lengths, padded to ``t_max`` steps, each batch one call of the
+    training kernel: member-samples, the real time steps, the time steps
+    the arrays hold and those the kernel runs (its Pallas layout, after
+    the length sort and the dead-chunk skip).  Read on the host: a device
+    op here would compile inside a traced window."""
     lens = [np.asarray(x) for x in lengths]
     return {
         "samples": members * sum(x.size for x in lens),
         "real_timesteps": members * sum(int(x.sum()) for x in lens),
         "padded_timesteps": members * sum(
             x.size * t for x, t in zip(lens, t_max)),
+        "kernel_timesteps": members * sum(
+            kops.train_kernel_timesteps(x, t) for x, t in zip(lens, t_max)),
     }
 
 

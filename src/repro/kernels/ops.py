@@ -17,6 +17,7 @@ from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core import reservoir as core_res
 from repro.kernels import ref as kref
@@ -207,6 +208,17 @@ def train_forward(
     at 128) like ``streaming_logits``; ``block_b`` tiles the batch axis of
     the Pallas grid.  The XLA backend ignores both (its single fused scan
     has no tiling).
+
+    The kernel skips each batch block's time chunks past its longest row
+    (kernels.train), so where the batch spans several blocks and the
+    window several chunks, the rows run sorted by length, longest first:
+    rows of like length share a block and the short blocks stop early.
+    Each row's recurrence, accumulator and boundary latches depend only on
+    that row, so unsorting the outputs gives bit for bit what the unsorted
+    batch gives.  Under ``vmap`` over members with shared inputs (the
+    population evaluation), the sort and the gather of ``j_seq`` are not
+    batched: they run once per call.  ``train_kernel_timesteps`` counts
+    the time steps the kernel runs.
     """
     backend = _auto_backend(backend)
     nx = j_seq.shape[-1]
@@ -219,24 +231,55 @@ def train_forward(
     b, t = j_seq.shape[0], j_seq.shape[1]
     if lengths is None:
         lengths = jnp.full((b,), t, jnp.int32)
-    if chunk_t is None:
-        chunk_t = min(128, -(-t // 8) * 8)
+    chunk_t, sort = _train_layout(b, t, block_b, chunk_t)
+    lens = jnp.clip(lengths.astype(jnp.int32), 0, t)
+    order = None
+    if sort:
+        order = jnp.argsort(lens, descending=True)
+        j_seq, lens = j_seq[order], lens[order]
     n_pad = max(128, -(-nx // 128) * 128)
     jp = _pad_to(_pad_to(_pad_to(j_seq.astype(jnp.float32), 2, n_pad),
                          1, chunk_t), 0, block_b)
     Lp, qp = _ring_padded(q, nx, n_pad)
-    lens = _pad_to(jnp.clip(lengths.astype(jnp.int32), 0, t), 0, block_b)
     acc, x_last, x_prev, j_last = train_forward_pallas(
-        jp, Lp, qp, lens, p, q, nx,
+        jp, Lp, qp, _pad_to(lens, 0, block_b), p, q, nx,
         f=f, block_b=block_b, chunk_t=chunk_t,
         interpret=(backend == "interpret"),
     )
     dt = j_seq.dtype
     outer = acc[:b, :nx, :nx].reshape(b, nx * nx)
     sums = acc[:b, :nx, nx]
-    r = jnp.concatenate([outer, sums], axis=-1).astype(dt)
-    return (r, x_last[:b, :nx].astype(dt), x_prev[:b, :nx].astype(dt),
-            j_last[:b, :nx].astype(dt))
+    out = (jnp.concatenate([outer, sums], axis=-1).astype(dt),
+           x_last[:b, :nx].astype(dt), x_prev[:b, :nx].astype(dt),
+           j_last[:b, :nx].astype(dt))
+    if order is not None:
+        inv = jnp.argsort(order)
+        out = tuple(o[inv] for o in out)
+    return out
+
+
+def _train_layout(b: int, t: int, block_b: int, chunk_t: Optional[int]):
+    """The training kernel's time chunk for a (b, t) batch, and whether
+    its rows run sorted: only where they span several batch blocks and
+    several time chunks does the order change what the kernel skips."""
+    if chunk_t is None:
+        chunk_t = min(128, -(-t // 8) * 8)
+    return chunk_t, b > block_b and t > chunk_t
+
+
+def train_kernel_timesteps(lengths, t: int, *, block_b: int = 8,
+                           chunk_t: Optional[int] = None) -> int:
+    """Time steps the training kernel runs for one ``train_forward`` call
+    on a (B, ``t``) batch with these lengths, counted on the host: per
+    batch block, its live chunks x ``chunk_t`` x ``block_b``, after the
+    wrapper's length sort and the kernel's chunk skip."""
+    lens = np.clip(np.asarray(lengths, np.int64).reshape(-1), 0, t)
+    chunk_t, sort = _train_layout(lens.size, t, block_b, chunk_t)
+    if sort:
+        lens = np.sort(lens)[::-1]
+    lens = np.pad(lens, (0, (-lens.size) % block_b))
+    n_live = -(-lens.reshape(-1, block_b).max(axis=1) // chunk_t)
+    return int(n_live.sum()) * chunk_t * block_b
 
 
 # ---------------------------------------------------------------------------

@@ -38,6 +38,14 @@ masking bit for bit.
 
 Grid: (batch_blocks, time_chunks), time minor/sequential so the scratch
 carries across chunks (re-initialized at chunk 0 of every batch block).
+Dead chunks are skipped: a scalar-prefetch operand gives each batch block
+its live chunk count ceil(max(lengths) / chunk_t), the time loop runs only
+in chunks below it, and the input index of a dead chunk is clamped to the
+block's last live one, so no new DMA starts.  Every step of a skipped chunk
+has k >= every length in its block: it would freeze the state, add exactly
+zero to the accumulator and never latch the boundary, so the outputs are
+bit for bit those of the full grid (for finite states).  The init at chunk
+0 and the emit at the last chunk stay outside the gate.
 The ring-mix dot runs at ``Precision.HIGHEST``: the chip's default f32
 matmul rounds its operands to bf16, which the recurrence would carry
 through every step.  Same ring-padding contract as the other kernels
@@ -71,6 +79,7 @@ from repro.core.types import DOT_PRECISION
 
 
 def _train_forward_kernel(
+    nlive_ref,   # SMEM (batch_blocks,) int32: time chunks holding a live step
     j_ref,       # (chunk_t, block_b, n_pad) masked inputs for this block
     L_ref,       # (n_pad, n_pad) ring matrix (zero padded, ring lane mirrored)
     qpow_ref,    # (1, n_pad) ring powers
@@ -89,6 +98,7 @@ def _train_forward_kernel(
     chunk_t: int,
     n_nodes: int,
 ):
+    bb = pl.program_id(0)
     tc = pl.program_id(1)
     n_pad = state.shape[-1]
 
@@ -131,7 +141,11 @@ def _train_forward_kernel(
         state[...] = x_k
         return 0
 
-    jax.lax.fori_loop(0, chunk_t, step, 0)
+    # a chunk past the block's longest length would only freeze the state
+    # and add zeros: skip it (its input index is clamped, so no DMA either)
+    @pl.when(tc < nlive_ref[bb])
+    def _run():
+        jax.lax.fori_loop(0, chunk_t, step, 0)
 
     @pl.when(tc == pl.num_programs(1) - 1)
     def _emit():
@@ -159,7 +173,9 @@ def train_forward_pallas(
 
     Returns ``(acc, x_last, x_prev, j_last)`` with shapes
     ``(B, n_pad, n_pad)``, ``(B, n_pad)`` x3.  ``ops.train_forward`` owns
-    the padding and the accumulator -> r conversion.
+    the padding, the length sort and the accumulator -> r conversion.
+    Each batch block runs only its first ceil(max(lengths) / chunk_t)
+    time chunks; the outputs are those of the full grid.
     """
     b, t_pad, n_pad = j_seq.shape
     assert t_pad % chunk_t == 0, (t_pad, chunk_t)
@@ -171,28 +187,30 @@ def train_forward_pallas(
         _train_forward_kernel, f=f, chunk_t=chunk_t, n_nodes=n_nodes
     )
     pq = jnp.stack([p.astype(jnp.float32), q.astype(jnp.float32)]).reshape(1, 2)
-    grid = (b // block_b, t_pad // chunk_t)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
+    lens = lengths.astype(jnp.int32)
+    # per batch block: the time chunks that hold a live step of any row
+    n_live = -(-jnp.max(lens.reshape(-1, block_b), axis=1) // chunk_t)
+
+    def j_index(bb, tc, n_live):
+        # a dead chunk re-reads the block's last live one: no new DMA
+        return (jnp.minimum(tc, jnp.maximum(n_live[bb] - 1, 0)), bb, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(b // block_b, t_pad // chunk_t),
         in_specs=[
-            pl.BlockSpec((chunk_t, block_b, n_pad), lambda bb, tc: (tc, bb, 0)),
-            pl.BlockSpec((n_pad, n_pad), lambda bb, tc: (0, 0)),
-            pl.BlockSpec((1, n_pad), lambda bb, tc: (0, 0)),
-            pl.BlockSpec((block_b, 1), lambda bb, tc: (bb, 0)),
-            pl.BlockSpec((1, 2), lambda bb, tc: (0, 0)),
+            pl.BlockSpec((chunk_t, block_b, n_pad), j_index),
+            pl.BlockSpec((n_pad, n_pad), lambda bb, tc, n_live: (0, 0)),
+            pl.BlockSpec((1, n_pad), lambda bb, tc, n_live: (0, 0)),
+            pl.BlockSpec((block_b, 1), lambda bb, tc, n_live: (bb, 0)),
+            pl.BlockSpec((1, 2), lambda bb, tc, n_live: (0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((block_b, n_pad, n_pad), lambda bb, tc: (bb, 0, 0)),
-            pl.BlockSpec((block_b, n_pad), lambda bb, tc: (bb, 0)),
-            pl.BlockSpec((block_b, n_pad), lambda bb, tc: (bb, 0)),
-            pl.BlockSpec((block_b, n_pad), lambda bb, tc: (bb, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, n_pad, n_pad), jnp.float32),
-            jax.ShapeDtypeStruct((b, n_pad), jnp.float32),
-            jax.ShapeDtypeStruct((b, n_pad), jnp.float32),
-            jax.ShapeDtypeStruct((b, n_pad), jnp.float32),
+            pl.BlockSpec((block_b, n_pad, n_pad),
+                         lambda bb, tc, n_live: (bb, 0, 0)),
+            pl.BlockSpec((block_b, n_pad), lambda bb, tc, n_live: (bb, 0)),
+            pl.BlockSpec((block_b, n_pad), lambda bb, tc, n_live: (bb, 0)),
+            pl.BlockSpec((block_b, n_pad), lambda bb, tc, n_live: (bb, 0)),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_b, n_pad), jnp.float32),
@@ -200,8 +218,18 @@ def train_forward_pallas(
             pltpu.VMEM((block_b, n_pad), jnp.float32),
             pltpu.VMEM((block_b, n_pad), jnp.float32),
         ],
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=[
+            jax.ShapeDtypeStruct((b, n_pad, n_pad), jnp.float32),
+            jax.ShapeDtypeStruct((b, n_pad), jnp.float32),
+            jax.ShapeDtypeStruct((b, n_pad), jnp.float32),
+            jax.ShapeDtypeStruct((b, n_pad), jnp.float32),
+        ],
         interpret=interpret,
-    )(jt, L, qpow.reshape(1, -1), lengths.astype(jnp.int32).reshape(-1, 1), pq)
+    )(n_live, jt, L, qpow.reshape(1, -1), lens.reshape(-1, 1), pq)
 
 
 #: time steps folded per accumulator contraction in the XLA fallback — a

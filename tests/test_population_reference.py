@@ -186,11 +186,18 @@ def _search_spans(log_dir):
     return sorted(out, key=lambda s: s[1])
 
 
-def test_search_spans_and_their_stats(tmp_path):
+@pytest.fixture(scope="module")
+def traced(tmp_path_factory):
+    """One fit under a profiler: its search.* spans, the result, splits."""
+    log_dir = tmp_path_factory.mktemp("search_trace")
     _fit()                                   # compile outside the trace
-    with jax.profiler.trace(str(tmp_path)):
+    with jax.profiler.trace(str(log_dir)):
         res, train, evalb = _fit()
-    spans = _search_spans(tmp_path)
+    return _search_spans(log_dir), res, train, evalb
+
+
+def test_search_spans_and_their_stats(traced):
+    spans, res, train, evalb = traced
     names = [s[0] for s in spans]
     assert names.count("search.evaluate") == 2
     assert names.count("search.select") == 3
@@ -215,6 +222,47 @@ def test_search_spans_and_their_stats(tmp_path):
             assert st["real_timesteps"] == k * int(train.length[:n_sgd].sum())
             assert st["padded_timesteps"] == k * n_sgd * T_MAX
     assert res.history[-1]["round"] == 1
+
+
+def test_search_spans_count_kernel_timesteps(traced):
+    """By hand: T_MAX 12 runs as one chunk of 16 steps, so nothing is
+    sorted and every 8-row block with a live row runs 16 x 8 steps: the
+    evaluation's 18 + 6 rows are 3 + 1 blocks, the refinement's 4
+    minibatches of 4 one block each."""
+    spans, *_ = traced
+    k = DIVS ** 2
+    seen = set()
+    for name, _s, _e, st in spans:
+        if name == "search.evaluate":
+            assert st["kernel_timesteps"] == k * (3 + 1) * 16 * 8
+        if name == "search.refine":
+            assert st["kernel_timesteps"] == k * 4 * 16 * 8
+        seen.add(name)
+    assert {"search.evaluate", "search.refine"} <= seen
+
+
+def test_kernel_timesteps_on_net_lengths():
+    """NET's 803 + 534 lengths spread over 50-994 (T 994: chunks of 128,
+    1,024 steps): sorted by length, longest first, the 101 + 67 blocks
+    hold 462 + 307 live chunks of the 808 + 536 the full grid runs.  The
+    refinement's shuffled minibatches of 8 are one block each, not
+    sorted: the skip alone saves 4-10 % of their chunks."""
+    from repro.kernels import ops
+
+    def spread(n):
+        return 50 + (np.arange(n) * 945) // n
+
+    tr, ev = spread(803), spread(534)
+    assert ops.train_kernel_timesteps(tr, 994) == 462 * 128 * 8
+    assert ops.train_kernel_timesteps(ev, 994) == 307 * 128 * 8
+    rng = np.random.default_rng(0)
+    batches = rng.permutation(tr)[:800].reshape(100, 8)   # one block each
+    chunks = sum(ops.train_kernel_timesteps(x, 994) for x in batches)
+    assert 0.90 * 800 * 1024 < chunks < 0.96 * 800 * 1024
+    # one block or one chunk: no sort, only the skip
+    assert ops.train_kernel_timesteps([0] * 8, 994) == 0
+    assert ops.train_kernel_timesteps([5, 129, 0], 994) == 2 * 128 * 8
+    assert ops.train_kernel_timesteps([3, 0] * 9, 12) == 3 * 16 * 8
 
 
 def test_spans_compute_nothing_unrecorded(monkeypatch):

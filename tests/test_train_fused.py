@@ -189,6 +189,128 @@ def test_interpret_kernel_bitwise_matches_ref_oracle(q):
                                       err_msg=name)
 
 
+def _skip_lengths(pattern):
+    """(lengths, T, chunk_t) of a length pattern for the dead-chunk skip."""
+    if pattern == "spread":          # shuffled, several chunks a block
+        lens = 1 + (np.arange(40) * 300) // 40
+        np.random.default_rng(3).shuffle(lens)
+        return lens, 300, 64
+    if pattern == "zero_and_one":
+        return np.asarray([0, 1, 1, 0, 0, 0, 1, 0, 1, 1, 0, 0, 0, 0, 0,
+                           0]), 24, 8
+    if pattern == "chunk_edges":     # exactly at, one below, one above
+        return np.asarray([8, 16, 7, 9, 15, 17, 24, 8, 16, 16, 16, 16, 16,
+                           16, 16, 16]), 24, 8
+    if pattern == "dead_block":      # rows 8-15 all padding: n_live 0
+        return np.asarray([20] * 8 + [0] * 8 + [3, 11, 5, 2, 9, 1, 4, 6]), \
+            24, 8
+    if pattern == "ragged_b":        # B = 13, the last block half padding
+        return np.asarray([5, 24, 13, 1, 9, 17, 2, 22, 8, 3, 16, 11, 7]), \
+            24, 8
+    assert pattern == "full"         # nothing to skip
+    return np.full(16, 24), 24, 8
+
+
+def _logical(out, b, nx):
+    """The wrapper's logical outputs from the kernel's padded ones."""
+    acc, x_last, x_prev, j_last = (np.asarray(o) for o in out)
+    r = np.concatenate([acc[:b, :nx, :nx].reshape(b, nx * nx),
+                        acc[:b, :nx, nx]], axis=-1)
+    return r, x_last[:b, :nx], x_prev[:b, :nx], j_last[:b, :nx]
+
+
+@pytest.mark.parametrize("pattern", ["spread", "zero_and_one", "chunk_edges",
+                                     "dead_block", "ragged_b", "full"])
+def test_interpret_kernel_skips_dead_chunks_bitwise(pattern):
+    """The kernel skips each block's time chunks past its longest row, and
+    the wrapper sorts the rows by length first: both return bit for bit
+    what the oracle's full time loop returns on the unsorted operands."""
+    lens, t, chunk_t = _skip_lengths(pattern)
+    b, nx, block_b = lens.size, 5, 8
+    j_seq = jax.random.normal(jax.random.PRNGKey(b + t), (b, t, nx),
+                              jnp.float32)
+    lengths = jnp.asarray(lens, jnp.int32)
+    p, q, f = jnp.float32(0.3), jnp.float32(-0.45), jnp.tanh
+    jp = kops._pad_to(kops._pad_to(kops._pad_to(j_seq, 2, 128), 1, chunk_t),
+                      0, block_b)
+    Lp, qp = kops._ring_padded(q, nx, 128)
+    lp = kops._pad_to(lengths, 0, block_b)
+    ref = kref.train_forward_ref(jp, Lp, qp, lp, p, nx, f=f)
+    got = train_forward_pallas(jp, Lp, qp, lp, p, q, nx, f=f,
+                               block_b=block_b, chunk_t=chunk_t,
+                               interpret=True)
+    for g, r, name in zip(got, ref, ("acc", "x_last", "x_prev", "j_last")):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(r),
+                                      err_msg=name)
+    wrapped = kops.train_forward(j_seq, lengths, p, q, nx, f=f,
+                                 backend="interpret", chunk_t=chunk_t,
+                                 block_b=block_b)
+    for g, r, name in zip(wrapped, _logical(ref, b, nx),
+                          ("r", "x_last", "x_prev", "j_last")):
+        np.testing.assert_array_equal(np.asarray(g), r, err_msg=name)
+
+
+def _sort_operands(fn, *args):
+    """Shapes of the operands of every sort in ``fn``'s jaxpr."""
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "sort":
+                yield tuple(eqn.invars[0].aval.shape)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from walk(sub)
+    return list(walk(jax.make_jaxpr(fn)(*args).jaxpr))
+
+
+def _sort_case(b=24, t=50, seed=2):
+    j_seq = jax.random.normal(jax.random.PRNGKey(seed), (b, t, 4),
+                              jnp.float32)
+    lengths = jax.random.randint(jax.random.PRNGKey(seed + 1), (b,), 0,
+                                 t + 1).astype(jnp.int32)
+    return j_seq, lengths
+
+
+def _fwd(j, ln, p, q):
+    return kops.train_forward(j, ln, p, q, 4, f=jnp.tanh,
+                              backend="interpret", chunk_t=16)
+
+
+def test_train_forward_sort_commutes_with_a_batch_permutation():
+    j_seq, lengths = _sort_case()
+    p, q = jnp.float32(0.25), jnp.float32(0.5)
+    perm = np.random.default_rng(0).permutation(j_seq.shape[0])
+    base = _fwd(j_seq, lengths, p, q)
+    moved = _fwd(j_seq[perm], lengths[perm], p, q)
+    for a, m, name in zip(base, moved, ("r", "x_last", "x_prev", "j_last")):
+        np.testing.assert_array_equal(np.asarray(a)[perm], np.asarray(m),
+                                      err_msg=name)
+
+
+def test_train_forward_vmapped_over_members_matches_member_calls():
+    """The evaluation's form: members vmapped over shared inputs.  The
+    sort runs once on the (B,) lengths, not per member."""
+    j_seq, lengths = _sort_case()
+    ps = jnp.asarray([0.1, 0.25, 0.4, 0.7], jnp.float32)
+    qs = jnp.asarray([0.5, -0.3, 0.05, 0.8], jnp.float32)
+    vfwd = jax.vmap(_fwd, in_axes=(None, None, 0, 0))
+    got = vfwd(j_seq, lengths, ps, qs)
+    for k in range(ps.size):
+        for g, w in zip(got, _fwd(j_seq, lengths, ps[k], qs[k])):
+            np.testing.assert_array_equal(np.asarray(g)[k], np.asarray(w))
+    assert _sort_operands(vfwd, j_seq, lengths, ps, qs) == [(24,), (24,)]
+
+
+@pytest.mark.parametrize("b, t, sorted_", [
+    (24, 50, True),      # three blocks, four chunks
+    (8, 50, False),      # one block: the refinement's minibatch
+    (24, 16, False),     # one chunk
+])
+def test_train_forward_sorts_only_several_blocks_and_chunks(b, t, sorted_):
+    j_seq, lengths = _sort_case(b, t)
+    p, q = jnp.float32(0.25), jnp.float32(0.5)
+    shapes = _sort_operands(_fwd, j_seq, lengths, p, q)
+    assert bool(shapes) == sorted_, shapes
+
+
 def test_interpret_matches_scan_fallback():
     cfg, params, j_seq, _ = _setup(nx=4, t=13, b=5, seed=11)
     lengths = jnp.asarray([13, 1, 7, 13, 2], jnp.int32)
