@@ -49,14 +49,11 @@ changes.
 Quantized serving (``--quantize int8``, PR 7): armed slots answer from the
 int8 fused fast path (coded readout + reservoir state, integer compute,
 fp32 dequantized logits); scales calibrate online and fold at the ridge
-refresh boundaries, training stays fp32.  Step blocking
-(``--step-block T``) fuses up to T window rounds per slot into one
-dispatch; the served episode is exactly the ``--step-block 1`` one.  Both
-compose with ``--devices``:
+refresh boundaries, training stays fp32.  It composes with ``--devices``:
 
     PYTHONPATH=src python examples/online_edge.py --quantize int8
-    PYTHONPATH=src python examples/online_edge.py --step-block 4 \
-        --quantize int8 --devices 8
+    PYTHONPATH=src python examples/online_edge.py --quantize int8 \
+        --devices 8
 """
 import argparse
 
@@ -78,10 +75,8 @@ from repro.runtime import StreamRequest, StreamServer
 def _server_retirement_kw(args) -> dict:
     """Map --forget / --retire-window to StreamServer retirement kwargs.
 
-    ``refresh_mode`` stays ``None`` when the flag was not given, so
-    ``--config auto`` can plan it; the retirement policies still pin
-    ``incremental`` explicitly (a semantic requirement, not a tuning
-    choice - window retirement downdates a live factor)."""
+    The retirement policies pin ``refresh_mode='incremental'`` unless the
+    flag was given (window retirement downdates a live factor)."""
     picked = [f for f, v in (("--forget", args.forget),
                              ("--retire-window", args.retire_window),
                              ("--retirement", args.retirement)) if v is not None]
@@ -98,22 +93,16 @@ def _server_retirement_kw(args) -> dict:
     if args.retire_window is not None:
         return {"retirement": "window", "retire_window": args.retire_window,
                 "refresh_mode": args.refresh_mode or "incremental"}
-    return {"refresh_mode": args.refresh_mode}
+    return {"refresh_mode": args.refresh_mode or "recompute"}
 
 
 def _server_pipeline_kw(args) -> dict:
-    """Map the serving-pipeline flags to StreamServer kwargs (PR 5/6/8).
-
-    Unset knobs pass ``None`` through: the server resolves them to the
-    historical defaults, or - under ``--config auto`` - to the calibrated
-    planner's picks."""
+    """Map the serving-pipeline flags to StreamServer kwargs."""
     return {
         "pipeline_depth": args.pipeline_depth,
         "staging": "host" if args.host_staging else "device",
         "devices": args.devices,
         "quantize": args.quantize,
-        "step_block": args.step_block,
-        "config": args.config,
     }
 
 
@@ -141,16 +130,6 @@ def _fmt_ms(v) -> str:
     return "n/a" if np.isnan(v) else f"{v:.1f} ms"
 
 
-def _print_plan(server) -> None:
-    if server.plan is not None:
-        pl = server.plan
-        print(f"  auto config (calibrated planner): "
-              f"refresh_mode={server.refresh_mode}, "
-              f"refresh_cohorts={server.cohorts.n_cohorts}, "
-              f"step_block={server.step_block} "
-              f"(predicted {pl.predicted_samples_per_s:.0f} samples/s)")
-
-
 def _effective_max_streams(args) -> int:
     """Round --max-streams up to a multiple of --devices (equal shards)."""
     ms = args.max_streams
@@ -166,10 +145,9 @@ def _print_mesh(server) -> None:
         print(f"  slot mesh: {server.devices} devices x "
               f"{server.max_streams // server.devices} slots each "
               f"({jax.device_count()} XLA devices visible)")
-    if server.quantize != "none" or server.step_block > 1:
-        print(f"  serving fast path: quantize={server.quantize}, "
-              f"step_block={server.step_block} (training stays fp32; the "
-              f"episode schedule matches the unblocked fp32 server)")
+    if server.quantize != "none":
+        print(f"  serving fast path: quantize={server.quantize} (training "
+              f"stays fp32; the episode schedule matches the fp32 server)")
 
 
 def run_drift(args) -> None:
@@ -194,7 +172,6 @@ def run_drift(args) -> None:
     print(f"serving {len(streams)} drifting NARMA streams x {n} samples "
           f"(switch at sample {switches[0]}; retirement={policy})")
     _print_mesh(server)
-    _print_plan(server)
     tuner = _attach_autotuner(server, args)
     for s in streams:
         server.submit(s)
@@ -212,8 +189,7 @@ def run_drift(args) -> None:
     lat = server.latency_percentiles_ms()
     print(f"  step wall time p50 {_fmt_ms(lat['p50_ms'])} / "
           f"p99 {_fmt_ms(lat['p99_ms'])} over {server.global_step} rounds "
-          f"(p99 absorbs the one-time jit compile at these few rounds; "
-          f"bench_stream reports warmed steady-state step times)")
+          f"(p99 absorbs the one-time jit compile at these few rounds)")
     if server.pipeline_depth > 0:
         print(f"  pipeline depth {server.pipeline_depth}: dispatch p50 "
               f"{_fmt_ms(lat['dispatch_p50_ms'])}, drain (sync) p50 "
@@ -235,12 +211,11 @@ def main():
                     default=None,
                     help="periodic ridge refresh: re-factorize B (O(s^3)) "
                          "or keep a live rank-1-updated Cholesky factor per "
-                         "slot (O(s^2) solves); default recompute, or the "
-                         "planner's pick under --config auto")
-    ap.add_argument("--refresh-cohorts", type=int, default=None,
+                         "slot (O(s^2) solves); default recompute")
+    ap.add_argument("--refresh-cohorts", type=int, default=1,
                     help="stagger the refresh round over this many "
                          "round-robin slot cohorts (default 1 = global "
-                         "round, or the planner's pick under --config auto)")
+                         "round)")
     ap.add_argument("--forget", type=float, default=None, metavar="LAMBDA",
                     help="forgetting-factor retirement: decay (A, B) and "
                          "the live factor by lambda per accumulated sample "
@@ -284,24 +259,10 @@ def main():
                          "reservoir/DPRR/readout compute, fp32 dequantized "
                          "logits; scales fold at ridge-refresh boundaries "
                          "and training stays fp32 (requires device staging)")
-    ap.add_argument("--step-block", type=int, default=None, metavar="T",
-                    help="multi-sample step blocking: fuse up to T window "
-                         "rounds per slot into ONE dispatch (PR 7); blocks "
-                         "clamp at retirement boundaries so the served "
-                         "episode is exactly the T=1 one (requires device "
-                         "staging; default 1, or the planner's pick under "
-                         "--config auto)")
-    ap.add_argument("--config", choices=("auto",), default=None,
-                    help="'auto': fill the unset performance knobs "
-                         "(--refresh-mode / --refresh-cohorts / "
-                         "--step-block) from the calibrated cost-model "
-                         "planner (PR 8; first run on a host pays a few "
-                         "seconds of micro-calibration, persisted to "
-                         ".planner_calibration.json)")
     ap.add_argument("--host-staging", action="store_true",
-                    help="use the PR-4 host-staged batch build instead of "
-                         "the device-resident request pool (A/B baseline; "
-                         "bit-identical, slower)")
+                    help="use the host-staged batch build instead of "
+                         "the device-resident request pool (the parity "
+                         "reference; bit-identical, slower)")
     ap.add_argument("--drift", action="store_true",
                     help="serve piecewise-stationary NARMA streams and "
                          "report before/at/after-drift online accuracy")
@@ -350,7 +311,6 @@ def main():
           f"retirement={server.retirement}) - the paper's protocol, "
           f"train-while-serve")
     _print_mesh(server)
-    _print_plan(server)
     tuner = _attach_autotuner(server, args)
     for s in streams:
         server.submit(s)

@@ -10,11 +10,3 @@ from repro.runtime.scheduler import SlotScheduler  # noqa: F401
 from repro.runtime.server import Server, Request  # noqa: F401
 from repro.runtime.stream_server import StreamRequest, StreamServer  # noqa: F401
 from repro.runtime.autotuner import WarmPoolAutotuner  # noqa: F401
-from repro.runtime.planner import (  # noqa: F401
-    Calibration,
-    Plan,
-    Planner,
-    get_calibration,
-    predict_step_cost,
-    replay_bench_tables,
-)

@@ -74,114 +74,77 @@ slots frozen by a lane mask - so XLA never re-specializes as streams
 retire and refill (continuous batching).  Per-slot state isolation is
 structural: every lane of the vmapped step reads only its own state row.
 
-Device-resident serving pipeline (PR 5)
----------------------------------------
+One serving step program
+------------------------
 
-The paper's 1/13 computation-time win comes from keeping the whole
-train-while-infer loop on the accelerator, no per-sample host round trips.
-The software analogue is three orthogonal knobs (each independently
-regression-tested bit-for-bit against the synchronous host-staged path):
+A device-staged serving step is ONE dispatch of one program,
+``_stream_step_pool_impl`` (jitted, or under ``shard_map`` when
+``devices > 1``):
 
-* **Zero-copy request staging** (``staging='device'``, the default): a
-  stream's padded payload is uploaded ONCE - staged at ``submit``
-  (``core.types.RequestPool`` row), written into its slot row at admission
-  via one donated in-place row write - and the per-step ``(S, W, T, n_in)``
-  window batch is assembled *on device* by a cursor-indexed gather inside
-  the fused jitted step.  The per-step host work drops from rebuilding and
-  re-uploading the whole window batch in Python loops to shipping four
-  tiny ``(S,)`` control vectors.  The periodic cohort Ridge refresh is
-  folded into the same dispatch (``lax.cond``-gated on a traced due flag
-  with a fixed-shape padded cohort row set), so a serving step is ONE
-  program dispatch, refresh rounds included.  ``staging='host'`` retains
-  the PR-4 host-staged batch build (and honors ``cfg.dtype``, which the
-  PR-4 path silently upcast to float32).
+* **Device staging**: a stream's padded payload is uploaded ONCE - staged
+  at ``submit`` (``core.types.RequestPool`` row), written into its slot row
+  at admission via one donated in-place row write - and the per-step
+  ``(S, W, T, n_in)`` window batch is assembled *on device* by a
+  cursor-indexed gather inside the step.  The host ships only small
+  ``(S,)`` control vectors.  The periodic cohort Ridge refresh is folded
+  into the same program (``lax.cond``-gated on a traced due flag with a
+  fixed-shape padded cohort row set), so refresh rounds cost no extra
+  dispatch.  ``staging='host'`` keeps the host-built window batch and a
+  separate refresh dispatch: it is the reference the parity tests hold
+  the pool step's predictions and model state to, bit for bit.
 
 * **Buffer donation** (``donate=True``, the default): the batched
   ``OnlineState`` / ``WindowState`` trees (the ``(S, s, s)`` ``B``/``Lt``
-  leaves dominate) are donated to the step and refresh executables, so XLA
-  updates them in place instead of copying the dominant buffers every
-  dispatch.  Donation never changes numerics; ``donate=False`` keeps the
-  copying PR-4 dispatch for A/B comparison.
+  leaves dominate) are donated to the step, so XLA updates them in place.
+  Donation never changes numerics.
 
 * **Async pipelining** (``pipeline_depth=D``): predictions stay on device
   in a lag-``D`` ring; the host's per-step bookkeeping (accuracy,
-  completion, retire/refill scatter) for step ``k`` runs while the device
-  computes steps ``k+1 .. k+D``.  Only request completion or ``drain()``
-  synchronizes.  Slot lifecycle (admission/retirement) is cursor-driven
+  completion) for step ``k`` runs while the device computes steps
+  ``k+1 .. k+D``.  Slot lifecycle (admission/retirement) is cursor-driven
   and therefore dispatch-time exact: pipelining delays only the *metric*
   bookkeeping, never the serving schedule, so ``pipeline_depth=0`` is
-  bit-for-bit ``pipeline_depth=D`` over any episode.  Step wall time is
-  reported honestly: ``latency_percentiles_ms`` separates dispatch time
-  (host enqueue, never blocking on device compute) from drain time (the
-  actual synchronization cost), so pipelining cannot hide its sync bill.
+  bit-for-bit ``pipeline_depth=D`` over any episode.
+  ``latency_percentiles_ms`` separates dispatch time (host enqueue) from
+  drain time (the blocking device read).
 
-``bench_stream``'s ``pipeline`` table measures the three knobs against the
-PR-4 synchronous host-staged server (see ROADMAP "Landed (PR 5)" for the
-committed numbers).
-
-Slot-sharded serving (PR 6)
----------------------------
+Slot-sharded serving
+--------------------
 
 ``devices=n`` shards the slot axis over a 1-D ``("slot",)`` device mesh
 (``launch.mesh.make_slot_mesh``; the ``slot`` logical-axis rule in
 ``distributed.sharding``): device d owns the contiguous slot block
 ``[d * S/n, (d+1) * S/n)``, fixed for the server's lifetime.  Slots are
-independent streams, so the fused pool step runs under ``shard_map`` with
+independent streams, so the pool step runs under ``shard_map`` with
 every per-slot operand - batched ``OnlineState``, ``WindowState`` rings,
 the staged ``RequestPool``, the ``(S,)`` control vectors, and the padded
 refresh-cohort row set (rewritten to shard-local indices by
 ``RefreshCohorts.due_rows_fixed_sharded``) - partitioned over ``"slot"``
-and everything else replicated.  The device-local invariant: the hot path
-contains NO cross-device collective; admission resets, the cursor-indexed
-window gather, truncated-BP/accumulation, cohort Ridge refresh and sample
-retirement all touch only the local block, and a live slot never migrates
-between devices.  The ``lax.cond`` gates become per-device predicates
-(``jnp.any`` over the local shard) whose untaken branches are exact
-identities, so a sharded episode serves the single-device episode's
-predictions across every retirement mode and pipeline depth, with states
+and everything else replicated.  The step contains NO cross-device
+collective, and a live slot never migrates between devices.  The
+``lax.cond`` gates become per-device predicates (``jnp.any`` over the
+local shard) whose untaken branches are exact identities, so a sharded
+episode serves the single-device episode's predictions, with states
 equal under ``runtime.parity``'s rule: XLA compiles the slot-vmapped step
 for the device-local slot count, and a few per-slot reductions then round
-in another order (``tests/test_stream_sharded.py``).  Donation, zero-copy
-staging and the fused cohort refresh all survive sharding: payload uploads
-happen once (the owning device keeps the in-place row write, the others
-drop it), and a serving step is still ONE dispatch.  Try it on CPU with
+in another order (``tests/test_stream_sharded.py``).  Try it on CPU with
 ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` (e.g.
 ``python examples/online_edge.py --devices 8``).
 
-Quantized int8 fast path + step blocking (PR 7)
------------------------------------------------
+Quantized int8 serving
+----------------------
 
-Two serving-only accelerations (training, statistics and refresh math stay
-fp32 bit-for-bit):
-
-* ``quantize='int8'`` - the serving logits of ARMED slots come from the
-  int8 fused kernel (``kernels.streaming.streaming_step_pallas_q8`` /
-  its XLA oracle): readout weights and the recurrent reservoir state live
-  as int8 codes under per-slot symmetric scales, the reservoir mix, DPRR
-  accumulation and readout contract in int8 x int8 -> int32 integer
-  arithmetic, and only the final logits dequantize to fp32.  Calibration
-  is free: the fused serve step tracks the running reservoir-state absmax
-  in ``OnlineState.quant``, and the scales FOLD (requantize W, arm the
-  slot) inside the same cohort-refresh branch the Ridge re-solve already
-  rides - scale refresh costs zero extra dispatches and tracks every
-  retirement mode's weight updates.  Unarmed slots (no refresh boundary
-  crossed yet - e.g. during the SGD adaptation phase) serve fp32.  The
-  coded readout is ~4x smaller per slot than the fp32 ``(Ny, Nr)`` row
-  (BENCH_stream_quant records the measured accuracy band + throughput).
-
-* ``step_block=T`` - multi-sample step blocking: a ``lax.scan`` over the
-  fused pool step serves up to T windows per slot in ONE dispatch with one
-  stacked refresh-schedule upload and one prediction readback.  The host
-  clamps each block so no slot completes mid-block, so admissions (and
-  hence the entire continuous-batching schedule) land exactly where the
-  unblocked server puts them: a blocked episode reproduces the
-  ``step_block=1`` predictions exactly, across retirement modes,
-  pipeline depths and device counts.  ``step_block=1`` routes through the
-  PR-6 step functions unchanged (bitwise regression-pinned by
-  ``tests/golden/stream_fp32_golden.npz``).
-
-Both knobs compose with each other and with slot sharding
-(``tests/test_stream_quant.py``, ``tests/test_stream_sharded.py``).
+``quantize='int8'`` - the serving logits of ARMED slots come from the int8
+fused kernel (``kernels.streaming.streaming_step_pallas_q8`` / its XLA
+oracle): readout weights and the recurrent reservoir state live as int8
+codes under per-slot symmetric scales, the reservoir mix, DPRR
+accumulation and readout contract in int8 x int8 -> int32 integer
+arithmetic, and only the final logits dequantize to fp32.  The fused serve
+step tracks the running reservoir-state absmax in ``OnlineState.quant``,
+and the scales FOLD (requantize W, arm the slot) inside the cohort-refresh
+branch the Ridge re-solve already rides.  Unarmed slots (no refresh
+boundary crossed yet) serve fp32.  Training, statistics and refresh math
+stay fp32 bit for bit (``tests/test_stream_quant.py``).
 """
 from __future__ import annotations
 
@@ -319,7 +282,6 @@ def _step_core(
     quantize: str = "none",
     adapt_ratio: float = 1.2,
     adapt_warmup: int = 4,
-    chunk_t: Optional[int] = None,
 ) -> Tuple[OnlineState, Optional[WindowState], Array, Dict[str, Array]]:
     """One server step: infer-before-update + train for every live slot.
 
@@ -448,7 +410,6 @@ def _step_core(
             logits = ops.streaming_logits_slots(
                 j_seq, length, states.params.p, states.params.q,
                 states.params.W, states.params.b, cfg.n_nodes, f=f,
-                chunk_t=chunk_t,
             )
     if quantize == "int8":
         # int8 fast path for ARMED slots (scales folded at least once):
@@ -461,7 +422,7 @@ def _step_core(
             q_logits = ops.streaming_logits_slots_q8(
                 j_seq, length, states.params.p, states.params.q,
                 states.quant.Wq, states.quant.w_scale, states.quant.x_scale,
-                states.params.b, cfg.n_nodes, f=f, chunk_t=chunk_t,
+                states.params.b, cfg.n_nodes, f=f,
             )
         armed = states.quant.w_scale > 0
         logits = jnp.where(
@@ -573,22 +534,21 @@ def _stream_step_impl(
     retirement: str = "none",
     adapt_ratio: float = 1.2,
     adapt_warmup: int = 4,
-    chunk_t: Optional[int] = None,
 ) -> Tuple[OnlineState, Optional[WindowState], Array, Dict[str, Array]]:
-    """Host-staged serving step (the retained PR-4 fallback): the caller
-    builds and uploads the padded window batch; see ``_step_core``."""
+    """Host-staged serving step (the reference the pool step is held to):
+    the caller builds and uploads the padded window batch; see
+    ``_step_core``."""
     return _step_core(
         cfg, mask, states, fresh, fresh_mask, u, length, label, weight,
         live, lr, phase_steps, beta, forget, win,
         fused_infer=fused_infer, maintain_factor=maintain_factor,
         retirement=retirement,
         adapt_ratio=adapt_ratio, adapt_warmup=adapt_warmup,
-        chunk_t=chunk_t,
     )
 
 
 _STEP_STATICS = ("cfg", "fused_infer", "maintain_factor", "retirement",
-                 "adapt_ratio", "adapt_warmup", "chunk_t")
+                 "adapt_ratio", "adapt_warmup")
 _stream_step = jax.jit(_stream_step_impl, static_argnames=_STEP_STATICS)
 # donated twin: OnlineState (arg 2) and WindowState (arg 14) update in place
 _stream_step_donated = jax.jit(
@@ -645,7 +605,6 @@ def _stream_step_pool_impl(
     quantize: str = "none",
     adapt_ratio: float = 1.2,
     adapt_warmup: int = 4,
-    chunk_t: Optional[int] = None,
 ) -> Tuple[OnlineState, Optional[WindowState], Array]:
     """Device-resident serving step: cursor-indexed window gather from the
     staged ``RequestPool``, the fused serve step, and the cohort Ridge
@@ -675,7 +634,6 @@ def _stream_step_pool_impl(
         fused_infer=fused_infer, maintain_factor=maintain_factor,
         retirement=retirement, quantize=quantize,
         adapt_ratio=adapt_ratio, adapt_warmup=adapt_warmup,
-        chunk_t=chunk_t,
     )
 
     def _refresh(st: OnlineState) -> OnlineState:
@@ -702,7 +660,7 @@ def _stream_step_pool_impl(
 
 _POOL_STATICS = ("cfg", "fused_infer", "maintain_factor", "retirement",
                  "refresh_mode", "window", "quantize",
-                 "adapt_ratio", "adapt_warmup", "chunk_t")
+                 "adapt_ratio", "adapt_warmup")
 _stream_step_pool = jax.jit(
     _stream_step_pool_impl, static_argnames=_POOL_STATICS
 )
@@ -715,102 +673,8 @@ _stream_step_pool_donated = jax.jit(
 )
 
 
-def _stream_step_pool_block_impl(
-    cfg: DFRConfig,
-    mask: Array,
-    states: OnlineState,
-    fresh: OnlineState,
-    fresh_mask: Array,
-    pool: RequestPool,
-    cursor: Array,          # (S,) int32 cursors at the BLOCK start
-    live: Array,
-    lr: Array,
-    phase_steps: Array,
-    beta: Array,
-    forget: Array,
-    win: Optional[WindowState],
-    active_b: Array,        # (B,) bool: sub-step t actually runs
-    refresh_due_b: Array,   # (B,) bool per-sub-step refresh flags
-    refresh_rows_b: Array,  # (B, R) int32 per-sub-step padded cohort rows
-    refresh_ok_b: Array,    # (B, R) bool
-    fused_infer: bool = True,
-    maintain_factor: bool = False,
-    retirement: str = "none",
-    refresh_mode: str = "recompute",
-    window: int = 1,
-    quantize: str = "none",
-    adapt_ratio: float = 1.2,
-    adapt_warmup: int = 4,
-    chunk_t: Optional[int] = None,
-) -> Tuple[OnlineState, Optional[WindowState], Array]:
-    """Multi-sample step blocking: up to B = ``step_block`` consecutive
-    pool steps in ONE dispatch, a ``lax.scan`` over the fused serving step.
-
-    Each sub-step is exactly ``_stream_step_pool_impl`` (gather + serve +
-    cohort refresh) on an in-carry cursor advanced by ``window`` samples
-    per live slot per sub-step; the host ships one stacked refresh
-    schedule instead of B control uploads, and pays ONE dispatch + ONE
-    prediction readback for the whole block.  The schedule contract that
-    makes a blocked episode serve the unblocked one exactly:
-
-      * admission only happens at block starts (``fresh_mask`` is consumed
-        by sub-step 0 and zeroed in the carry), and
-      * the host clamps the active length so no live slot completes
-        mid-block (``StreamServer.step``) - so blocks end at every
-        retirement boundary and the slot lifecycle schedule is identical.
-
-    ``active_b`` keeps the executable fixed-shape: clamped blocks run with
-    tail sub-steps inactive (a ``lax.cond`` identity - dead sub-steps skip
-    the serve compute, not just its effects), so one program serves every
-    block length 1..B.  Returns predictions shaped (B, S, W); inactive
-    sub-steps yield zeros the host never reads.
-    """
-    S = live.shape[0]
-
-    def _sub(carry, xs):
-        st, w, cur, fm = carry
-        act, due, rows, ok = xs
-
-        def _run(oper):
-            st, w, cur, fm = oper
-            ns, nw, preds = _stream_step_pool_impl(
-                cfg, mask, st, fresh, fm, pool, cur, live, lr,
-                phase_steps, beta, forget, w, due, rows, ok,
-                fused_infer=fused_infer, maintain_factor=maintain_factor,
-                retirement=retirement, refresh_mode=refresh_mode,
-                window=window, quantize=quantize,
-                adapt_ratio=adapt_ratio, adapt_warmup=adapt_warmup,
-                chunk_t=chunk_t,
-            )
-            return ns, nw, preds.astype(jnp.int32)
-
-        def _skip(oper):
-            st, w, _, _ = oper
-            return st, w, jnp.zeros((S, window), jnp.int32)
-
-        ns, nw, preds = jax.lax.cond(act, _run, _skip, (st, w, cur, fm))
-        cur = cur + jnp.where(live & act, window, 0).astype(cur.dtype)
-        fm = jnp.zeros_like(fm)   # admissions only at the block start
-        return (ns, nw, cur, fm), preds
-
-    (states, win, _, _), preds = jax.lax.scan(
-        _sub, (states, win, cursor, fresh_mask),
-        (active_b, refresh_due_b, refresh_rows_b, refresh_ok_b),
-    )
-    return states, win, preds    # preds: (B, S, W)
-
-
-_stream_step_pool_block = jax.jit(
-    _stream_step_pool_block_impl, static_argnames=_POOL_STATICS
-)
-_stream_step_pool_block_donated = jax.jit(
-    _stream_step_pool_block_impl, static_argnames=_POOL_STATICS,
-    donate_argnums=(2, 12),
-)
-
-
 # ---------------------------------------------------------------------------
-# Slot-sharded serving (PR 6): the same fused pool step, shard_map'd over a
+# Slot-sharded serving: the same fused pool step, shard_map'd over a
 # 1-D ("slot",) device mesh.  Slots are embarrassingly parallel, so every
 # per-slot operand (states / ring buffers / staged pool / control vectors /
 # the padded refresh-cohort row set, rewritten to shard-LOCAL indices by
@@ -834,13 +698,6 @@ _SLOT, _REP = P("slot"), P()
 _POOL_IN_SPECS = (_REP, _SLOT, _REP, _SLOT, _SLOT, _SLOT, _SLOT, _REP,
                   _REP, _REP, _REP, _SLOT, _REP, _SLOT, _SLOT)
 _POOL_OUT_SPECS = (_SLOT, _SLOT, _SLOT)      # states, win, preds
-# blocked twin: the stacked (B, R) cohort row sets shard their SECOND axis
-# (shard-local fixed-width blocks per device, one row set per sub-step);
-# the (B,) active/due flags replicate; preds (B, S, W) shard axis 1
-_BLOCK_IN_SPECS = (_REP, _SLOT, _REP, _SLOT, _SLOT, _SLOT, _SLOT, _REP,
-                   _REP, _REP, _REP, _SLOT, _REP, _REP,
-                   P(None, "slot"), P(None, "slot"))
-_BLOCK_OUT_SPECS = (_SLOT, _SLOT, P(None, "slot"))
 _SHARDED_STEP_CACHE: Dict[Tuple, object] = {}
 _SHARDED_WRITE_CACHE: Dict[Mesh, object] = {}
 
@@ -856,28 +713,6 @@ def _sharded_pool_step(mesh: Mesh, cfg: DFRConfig, donate: bool, **statics):
         body = jax.shard_map(
             partial(_stream_step_pool_impl, cfg, **statics),
             mesh=mesh, in_specs=_POOL_IN_SPECS, out_specs=_POOL_OUT_SPECS,
-            check_vma=False,
-        )
-        hit = _SHARDED_STEP_CACHE[key] = jax.jit(
-            body, donate_argnums=(1, 11) if donate else ()
-        )
-    return hit
-
-
-def _sharded_pool_block_step(
-    mesh: Mesh, cfg: DFRConfig, donate: bool, **statics
-):
-    """jit(shard_map(_stream_step_pool_block_impl)): the step-blocked scan
-    with every sub-step acting on the device-local slot block.  The scan
-    carries only slot-sharded or replicated values and the body is the
-    collective-free pool step, so a blocked sharded episode matches the
-    blocked single-device episode (same argument as the unblocked twin)."""
-    key = ("block", mesh, cfg, donate, tuple(sorted(statics.items())))
-    hit = _SHARDED_STEP_CACHE.get(key)
-    if hit is None:
-        body = jax.shard_map(
-            partial(_stream_step_pool_block_impl, cfg, **statics),
-            mesh=mesh, in_specs=_BLOCK_IN_SPECS, out_specs=_BLOCK_OUT_SPECS,
             check_vma=False,
         )
         hit = _SHARDED_STEP_CACHE[key] = jax.jit(
@@ -1069,59 +904,35 @@ class StreamServer:
         contract: an episode in which the detector never fires serves
         the ``retirement='none'`` episode (``runtime.parity``'s rule).
 
-    Serving pipeline (PR 5, see the module docstring):
+    The serving step (see the module docstring) is one device-staged pool
+    step program, shard-mapped when ``devices > 1``:
 
-      * ``staging='device'`` (default) - zero-copy request staging: payloads
-        upload once, the window batch is gathered on device and the cohort
-        refresh folds into the same single dispatch.  ``'host'`` retains
-        the PR-4 per-step host batch build.
-      * ``donate=True`` (default) - the step/refresh executables update the
-        batched state trees in place (never changes numerics).
+      * ``staging='device'`` (default) - payloads upload once, the window
+        batch is gathered on device and the cohort refresh folds into the
+        same single dispatch.  ``'host'`` builds the window batch on the
+        host each step and refreshes in a separate dispatch: the reference
+        the parity tests compare the pool step against.
+      * ``donate=True`` (default) - the step updates the batched state
+        trees in place (never changes numerics).
       * ``pipeline_depth=D`` - overlap host bookkeeping for step k with
         device compute of steps k+1..k+D; predictions ride a lag-D device
-        ring drained by ``drain()`` / completion.  D=0 is the synchronous
-        PR-4 schedule bit-for-bit.
+        ring drained by ``drain()`` / completion.  D=0 is synchronous, and
+        every depth serves the same episode bit for bit.
       * ``pool_capacity`` - pre-size the staged pool (samples per slot row,
         rounded up to a window multiple).  Leave None to let it grow to the
         largest submitted stream (each growth re-specializes the jitted
         gather, so pre-sizing is worth it when stream lengths are known).
       * ``devices=n`` - shard the slot axis over the first n devices
         (``S % n == 0``, ``staging='device'``; see the module docstring's
-        slot-sharding section).  Bitwise the devices=1 episode; scales
-        served-samples/sec with the device count (BENCH_stream_sharded).
-
-    Quantized serving fast path (PR 7):
-
+        slot-sharding section).  The devices=1 episode under
+        ``runtime.parity``'s rule.
       * ``quantize='int8'`` - armed slots serve from int8 codes (readout
         weights + recurrent reservoir state, symmetric per-slot scales;
         int8 x int8 -> int32 reservoir/DPRR/readout compute, fp32
         dequantized logits).  Scales calibrate from the running state
         absmax and fold at ridge-refresh boundaries; a slot serves fp32
         until its first fold (``w_scale == 0``), and training/statistics
-        stay fp32 always.  Requires ``staging='device'``.  ~4x smaller
-        serving-state readout bytes per slot; accuracy cost measured
-        honestly in BENCH_stream_quant.
-      * ``step_block=T`` - multi-sample step blocking: up to T consecutive
-        serving steps (T windows per slot) fuse into ONE dispatch via a
-        ``lax.scan`` over the pool step, amortizing dispatch overhead and
-        per-step control uploads.  Blocks clamp so no slot completes
-        mid-block, making the blocked episode serve the ``step_block=1``
-        episode exactly (same admissions, same refresh schedule, same
-        predictions).  Requires ``staging='device'``.  T=1 routes through
-        the unchanged PR-6 step functions.
-
-    Auto-configuration (PR 8):
-
-      * ``config='auto'`` - fill the pure-performance knobs the caller
-        left unset (``refresh_mode``, ``refresh_cohorts``, ``step_block``)
-        from ``runtime.planner``'s calibrated cost model instead of the
-        static defaults; the chosen ``Plan`` is exposed as ``self.plan``.
-        Explicitly passed knobs always override the planner, and without
-        ``config='auto'`` unset knobs resolve to the historical defaults
-        (recompute / 1 / 1) - existing call sites are bitwise unchanged.
-        The first auto server on a host pays a few seconds of
-        micro-calibration, persisted to ``.planner_calibration.json``
-        (override via ``REPRO_PLANNER_CAL``) so later servers skip it.
+        stay fp32 always.  Requires ``staging='device'``.
     """
 
     def __init__(
@@ -1136,8 +947,8 @@ class StreamServer:
         beta: float = 1e-2,
         mask: Optional[Array] = None,
         fused_infer: Optional[bool] = None,
-        refresh_mode: Optional[str] = None,
-        refresh_cohorts: Optional[int] = None,
+        refresh_mode: str = "recompute",
+        refresh_cohorts: int = 1,
         retirement: str = "none",
         forget: float = 1.0,
         retire_window: int = 0,
@@ -1151,44 +962,7 @@ class StreamServer:
         latency_window: int = 4096,
         devices: int = 1,
         quantize: str = "none",
-        step_block: Optional[int] = None,
-        chunk_t: Optional[int] = None,
-        config: Optional[str] = None,
     ):
-        # -- config='auto': fill UNSET performance knobs from the calibrated
-        # cost-model planner (runtime.planner).  Explicit knobs always win,
-        # so any PR-7 call site resolves to bitwise-identical behavior; only
-        # the pure-performance knobs (refresh_mode / refresh_cohorts /
-        # step_block) are planned - semantic knobs (retirement, quantize,
-        # staging, devices) are constraints the planner respects, never
-        # choices it makes.
-        if config not in (None, "auto"):
-            raise ValueError(f"unknown config: {config!r} (None or 'auto')")
-        self.plan = None
-        if config == "auto":
-            from repro.runtime import planner as _planner
-
-            _pl = _planner.Planner(
-                cfg.n_nodes, max_streams, window, t_max,
-                n_classes=cfg.n_classes, refresh_every=refresh_every,
-                retirement=retirement, quantize=quantize, staging=staging,
-            )
-            self.plan = _pl.search()
-            if refresh_mode is None:
-                refresh_mode = self.plan.refresh_mode
-            if refresh_cohorts is None:
-                refresh_cohorts = self.plan.refresh_cohorts
-            if step_block is None:
-                step_block = self.plan.step_block
-            if chunk_t is None:
-                chunk_t = self.plan.chunk_t
-        # unset knobs without config='auto' keep the historical defaults
-        if refresh_mode is None:
-            refresh_mode = "recompute"
-        if refresh_cohorts is None:
-            refresh_cohorts = 1
-        if step_block is None:
-            step_block = 1
         if refresh_mode not in ("recompute", "incremental"):
             raise ValueError(f"unknown refresh_mode: {refresh_mode!r}")
         if retirement not in ("none", "forget", "window", "adaptive"):
@@ -1238,19 +1012,6 @@ class StreamServer:
                 "quantize='int8' requires staging='device' (the scale fold "
                 "rides the fused cohort refresh of the pool step)"
             )
-        if step_block < 1:
-            raise ValueError(
-                f"step_block must be >= 1, got {step_block!r}"
-            )
-        if chunk_t is not None and chunk_t < 1:
-            raise ValueError(
-                f"chunk_t must be None or >= 1, got {chunk_t!r}"
-            )
-        if step_block > 1 and staging != "device":
-            raise ValueError(
-                "step_block > 1 requires staging='device' (the blocked scan "
-                "gathers every sub-step's window from the staged pool)"
-            )
         if devices > 1:
             if staging != "device":
                 raise ValueError(
@@ -1288,10 +1049,6 @@ class StreamServer:
         self.pipeline_depth = int(pipeline_depth)
         self.donate = bool(donate)
         self.quantize = quantize
-        self.step_block = int(step_block)
-        # Pallas time-chunk size for the fused streaming kernels; None keeps
-        # the per-shape heuristic in kernels.ops (also the XLA-backend no-op)
-        self.chunk_t = None if chunk_t is None else int(chunk_t)
         self._np_dtype = np.dtype(cfg.dtype)
         self.cohorts = RefreshCohorts(
             self.max_streams, self.refresh_every, refresh_cohorts
@@ -1374,7 +1131,6 @@ class StreamServer:
         # fresh masks only move on admission/retirement)
         self._mask_cache: Dict[bytes, Array] = {}
         self._due_cache: Dict[int, Tuple[Array, Array, Array]] = {}
-        self._due_block_cache: Dict[Tuple, Tuple] = {}
         self.global_step = 0
         self._autotuner = None  # optional WarmPoolAutotuner (attach_autotuner)
         # async pipeline: (device preds, per-slot bookkeeping meta, global
@@ -1557,40 +1313,6 @@ class StreamServer:
             )
         return hit
 
-    def _cached_due_block(
-        self, start: int, b_active: int
-    ) -> Tuple[Array, Array, Array, Array]:
-        """Stacked refresh schedule for a step block: the per-sub-step
-        (due, rows, ok) triples for steps ``start .. start + B - 1`` plus
-        the (B,) active flags for a clamped block.  The schedule cycles
-        with period ``refresh_every`` (like ``_cached_due``), so the
-        device copies are cached by (phase, active length)."""
-        key = (start % self.refresh_every, b_active)
-        hit = self._due_block_cache.get(key)
-        if hit is None:
-            B = self.step_block
-            dues, rows, oks = [], [], []
-            for t in range(B):
-                if self.devices > 1:
-                    d, r, o = self.cohorts.due_rows_fixed_sharded(
-                        start + t, self.devices
-                    )
-                else:
-                    d, r, o = self.cohorts.due_rows_fixed(start + t)
-                dues.append(np.asarray(d))
-                rows.append(np.asarray(r))
-                oks.append(np.asarray(o))
-            active = np.arange(B) < b_active
-            # inactive tail sub-steps are cond-skipped anyway; zeroing
-            # their due flags keeps the cached schedule canonical
-            hit = self._due_block_cache[key] = (
-                jnp.asarray(active),
-                jnp.asarray(np.stack(dues).astype(bool) & active),
-                jnp.asarray(np.stack(rows)),
-                jnp.asarray(np.stack(oks)),
-            )
-        return hit
-
     # -- the serving loop --------------------------------------------------------
 
     def step(self) -> None:
@@ -1600,10 +1322,11 @@ class StreamServer:
         ``staging='device'`` gathers the window batch on device from the
         staged pool (the host ships only (S,)-sized control vectors) and
         folds any due cohort refresh into the same dispatch;
-        ``staging='host'`` retains the PR-4 build-pad-upload loop and the
-        separate refresh dispatch.  Predictions enter the in-flight ring;
-        entries deeper than ``pipeline_depth`` are drained (the only
-        blocking device read), so depth 0 is fully synchronous.
+        ``staging='host'`` builds and uploads the window batch on the host
+        and refreshes in a separate dispatch (the parity tests' reference).
+        Predictions enter the in-flight ring; entries deeper than
+        ``pipeline_depth`` are drained (the only blocking device read), so
+        depth 0 is fully synchronous.
 
         The step is a ``stream.step`` span holding its ``stream.admit``,
         ``stream.enqueue``, ``stream.snapshot``, ``stream.retire`` and
@@ -1616,11 +1339,10 @@ class StreamServer:
             first = self.global_step + 1
             self._admitted_this_step.clear()
             self.sched.admit(self._on_admit)
-            live, fresh_mask, meta, b_active = self._plan_step()
-            due = any(self.cohorts.due_cohort(first + t) is not None
-                      for t in range(b_active))
+            live, fresh_mask, meta = self._plan_step()
+            due = self.cohorts.due_cohort(first) is not None
             with tracing.span("stream.enqueue", refresh=due):
-                preds = self._enqueue(fresh_mask, live, meta, b_active)
+                preds = self._enqueue(fresh_mask, live, meta)
             retired, snapshots = self._retire(meta)
             self._inflight.append((preds, meta, self.global_step))
             if self._autotuner is not None:
@@ -1631,80 +1353,34 @@ class StreamServer:
             self.step_times_s.append(time.perf_counter() - t_start)
             if tracing.recording():
                 span.set_metadata(**self._step_stats(
-                    first, meta, b_active, int(live.sum()), retired,
-                    snapshots,
+                    first, meta, int(live.sum()), retired, snapshots,
                 ))
 
-    def _plan_step(self) -> Tuple[np.ndarray, np.ndarray, List[Tuple], int]:
-        """(live, fresh_mask, meta, b_active) of this step: the (S,) live
-        and admission masks, one (sub-step, slot, request, cursor, samples)
-        entry per live slot and sub-step, and the sub-steps a block runs."""
+    def _plan_step(self) -> Tuple[np.ndarray, np.ndarray, List[Tuple]]:
+        """(live, fresh_mask, meta) of this step: the (S,) live and
+        admission masks and one (slot, request, cursor, samples) entry per
+        live slot."""
         S, W = self.max_streams, self.window
         live = np.zeros((S,), bool)
         fresh_mask = np.zeros((S,), bool)
         fresh_mask[self._admitted_this_step] = True
-        slots = list(self.sched.live())
         meta: List[Tuple] = []
-        for i, req in slots:
+        for i, req in self.sched.live():
             lo = int(self.slot_pos[i])
-            n = min(W, req.n_samples - lo)
             live[i] = True
-            meta.append((0, i, req, lo, n))
-
-        # step blocking: clamp the block so no live slot completes inside
-        # it - blocks then end at every retirement boundary, so admission
-        # timing (and with it the whole slot lifecycle schedule) matches
-        # the step_block=1 episode exactly
-        b_active = 1
-        if self.step_block > 1 and slots:
-            b_active = self.step_block
-            for _t, i, req, lo, n in meta:
-                b_active = min(b_active, -(-(req.n_samples - lo) // W))
-            b_active = max(1, b_active)
-            for t in range(1, b_active):
-                for i, req in slots:
-                    lo = int(self.slot_pos[i]) + t * W
-                    n = min(W, req.n_samples - lo)
-                    meta.append((t, i, req, lo, n))
-        return live, fresh_mask, meta, b_active
+            meta.append((i, req, lo, min(W, req.n_samples - lo)))
+        return live, fresh_mask, meta
 
     def _enqueue(
         self, fresh_mask: np.ndarray, live: np.ndarray, meta: List[Tuple],
-        b_active: int,
     ) -> Array:
         """Enqueue this step's program(s) and advance ``global_step``;
         returns the predictions, still on device."""
         S, W, T = self.max_streams, self.window, self.t_max
-        step_kw = self._step_kw()
         if self.staging == "device":
-            pool_kw = dict(
-                refresh_mode=self.refresh_mode, window=W,
-                quantize=self.quantize, **step_kw,
-            )
-            operands = self._pool_operands(fresh_mask, live)
-            if self.step_block > 1:
-                active, due_b, rows_b, ok_b = self._cached_due_block(
-                    self.global_step + 1, b_active
-                )
-                if self.mesh is not None:
-                    step_fn = _sharded_pool_block_step(
-                        self.mesh, self.cfg, self.donate, **pool_kw
-                    )
-                    self.states, self.win, preds = step_fn(
-                        *operands, active, due_b, rows_b, ok_b
-                    )
-                else:
-                    step_fn = (_stream_step_pool_block_donated if self.donate
-                               else _stream_step_pool_block)
-                    self.states, self.win, preds = step_fn(
-                        self.cfg, *operands, active, due_b, rows_b, ok_b,
-                        **pool_kw,
-                    )
-                self.global_step += b_active
-            else:
-                step_fn, args, kw = self._pool_step_call(operands, pool_kw)
-                self.states, self.win, preds = step_fn(*args, **kw)
-                self.global_step += 1
+            step_fn, args, kw = self._pool_step_call(fresh_mask, live)
+            self.states, self.win, preds = step_fn(*args, **kw)
+            self.global_step += 1
             return preds
         # host staging: rebuild + upload the padded window batch (in
         # cfg.dtype - an earlier version hardcoded float32 here, silently
@@ -1713,7 +1389,7 @@ class StreamServer:
         length = np.ones((S, W), np.int32)  # dead samples: len 1, w 0
         label = np.zeros((S, W), np.int32)
         weight = np.zeros((S, W), self._np_dtype)
-        for _t, i, req, lo, n in meta:
+        for i, req, lo, n in meta:
             u[i, :n] = req.u[lo:lo + n]
             length[i, :n] = req.length[lo:lo + n]
             label[i, :n] = req.label[lo:lo + n]
@@ -1724,7 +1400,8 @@ class StreamServer:
             jnp.asarray(fresh_mask),
             jnp.asarray(u), jnp.asarray(length), jnp.asarray(label),
             jnp.asarray(weight), jnp.asarray(live), self.lr,
-            self.phase_steps, self.beta, self.forget, self.win, **step_kw,
+            self.phase_steps, self.beta, self.forget, self.win,
+            **self._step_kw(),
         )
         self.global_step += 1
         due = self.cohorts.due_slots(self.global_step)
@@ -1752,13 +1429,10 @@ class StreamServer:
         Dispatch-time bookkeeping: the slot lifecycle is cursor-driven
         (independent of prediction values), so retirement/refill never
         waits on the device - only the metric bookkeeping rides the ring.
-        Meta is sub-step-major, so a blocked step's cursor advances
-        accumulate in schedule order and a slot retires exactly at its
-        block's end (the clamp guarantees no earlier completion).  The
-        completed streams' final models are snapshot together, after this
-        step's program and before the next admission."""
+        The completed streams' final models are snapshot together, after
+        this step's program and before the next admission."""
         done = []
-        for _t, i, req, lo, n in meta:
+        for i, req, lo, n in meta:
             self.slot_pos[i] += n
             if self.slot_pos[i] >= req.n_samples:
                 done.append((i, req))
@@ -1773,8 +1447,8 @@ class StreamServer:
         return len(done), programs
 
     def _step_stats(
-        self, first: int, meta: List[Tuple], b_active: int, n_live: int,
-        n_retired: int, n_snapshots: int,
+        self, first: int, meta: List[Tuple], n_live: int, n_retired: int,
+        n_snapshots: int,
     ) -> Dict[str, int]:
         """The ``stream.step`` span's counters, from host values only (the
         device is never read).  ``refresh_eligible`` counts the due rows
@@ -1785,26 +1459,23 @@ class StreamServer:
         the live windows against the ``slot_timesteps`` the kernels run;
         ``snapshot_programs`` the retirement snapshot programs dispatched."""
         rows = eligible = 0
-        for t in range(b_active):
-            c = self.cohorts.due_cohort(first + t)
-            if c is None:
-                continue
-            rows += self._refresh_width(first + t)
-            eligible += sum(
-                1 for tt, i, _req, lo, _n in meta
-                if tt == t and self.cohorts.cohort_of_slot[i] == c
+        c = self.cohorts.due_cohort(first)
+        if c is not None:
+            rows = self._refresh_width(first)
+            eligible = sum(
+                1 for i, _req, lo, _n in meta
+                if self.cohorts.cohort_of_slot[i] == c
                 and lo // self.window >= self._phase_steps
             )
         real = sum(int(req.length[lo:lo + n].sum())
-                   for _t, _i, req, lo, n in meta)
+                   for _i, req, lo, n in meta)
         return dict(
             step=self.global_step, live=n_live,
             admitted=len(self._admitted_this_step), retired=n_retired,
             snapshot_programs=n_snapshots,
             refresh_rows=rows, refresh_eligible=eligible,
             real_timesteps=real,
-            slot_timesteps=self.max_streams * self.window * self.t_max
-            * b_active,
+            slot_timesteps=self.max_streams * self.window * self.t_max,
         )
 
     def _refresh_width(self, step: int) -> int:
@@ -1826,21 +1497,22 @@ class StreamServer:
             retirement=self.retirement,
             adapt_ratio=self.adapt_ratio,
             adapt_warmup=self.adapt_warmup,
-            chunk_t=self.chunk_t,
         )
 
-    def _pool_operands(self, fresh_mask: np.ndarray, live: np.ndarray):
-        return (
+    def _pool_step_call(self, fresh_mask: np.ndarray, live: np.ndarray):
+        """(jitted program, args, kwargs) of one device-staged pool step
+        with the refresh schedule of the next global step.  The program is
+        looked up at call time, so a module-level replacement takes
+        effect."""
+        pool_kw = dict(refresh_mode=self.refresh_mode, window=self.window,
+                       quantize=self.quantize, **self._step_kw())
+        operands = (
             self.mask, self.states, self._fresh_row,
             self._cached_mask(fresh_mask), self.pool,
             jnp.asarray(self.slot_pos.astype(np.int32)),
             self._cached_mask(live), self.lr, self.phase_steps,
             self.beta, self.forget, self.win,
         )
-
-    def _pool_step_call(self, operands: Tuple, pool_kw: Dict):
-        """(jitted program, args, kwargs) of one unblocked device-staged
-        step with the refresh schedule of the next global step."""
         due, rows, ok = self._cached_due(self.global_step + 1)
         if self.mesh is not None:
             step_fn = _sharded_pool_step(
@@ -1852,17 +1524,14 @@ class StreamServer:
         return step_fn, (self.cfg, *operands, due, rows, ok), pool_kw
 
     def step_program_text(self) -> str:
-        """Optimized HLO of the unblocked device-staged step program at the
+        """Optimized HLO of the device-staged pool step program at the
         server's current shapes: Pallas kernels appear as
         ``tpu_custom_call``, cross-device traffic as collectives (the
         slot-sharded step has none)."""
         if self.staging != "device":
             raise ValueError("step_program_text needs staging='device'")
         idle = np.zeros((self.max_streams,), bool)
-        pool_kw = dict(refresh_mode=self.refresh_mode, window=self.window,
-                       quantize=self.quantize, **self._step_kw())
-        step_fn, args, kw = self._pool_step_call(
-            self._pool_operands(idle, idle), pool_kw)
+        step_fn, args, kw = self._pool_step_call(idle, idle)
         return step_fn.lower(*args, **kw).compile().as_text()
 
     def _drain_one(self) -> None:
@@ -1873,11 +1542,9 @@ class StreamServer:
             t0 = time.perf_counter()
             preds_np = np.asarray(preds)   # blocks: the served predictions
             self.drain_times_s.append(time.perf_counter() - t0)
-            for t, i, req, lo, n in meta:
-                # blocked steps return (B, S, W); unblocked return (S, W)
-                block = preds_np[t] if preds_np.ndim == 3 else preds_np
+            for i, req, lo, n in meta:
                 for k in range(n):
-                    pred = int(block[i, k])
+                    pred = int(preds_np[i, k])
                     req.preds.append(pred)
                     req.correct += int(pred == int(req.label[lo + k]))
                 if lo + n >= req.n_samples:

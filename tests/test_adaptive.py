@@ -8,7 +8,7 @@ Contracts under test:
     episode identical to ``retirement='none'`` under the parity rule of
     ``repro.runtime.parity`` (the anneal is cond-gated; only the two
     detector EMA leaves move, and the loss EMA may round differently) -
-    across device staging, step blocking and int8 serving.  On the shared drift fixture
+    across device staging, pipelining and int8 serving.  On the shared drift fixture
     it recovers post-switch accuracy without being told the drift point.
   * ``online.adaptive_anneal``: trip semantics (update/armed/init gating,
     slow-baseline re-arm), the anneal's ``Lt^T Lt == B + factor_beta I``
@@ -85,7 +85,7 @@ def _state_leaves(srv):
 
 SILENCE_MODES = (
     ("plain", {}),
-    ("blocked", {"step_block": 4}),
+    ("pipelined", {"pipeline_depth": 2}),
     ("int8", {"quantize": "int8"}),
     ("host", {"staging": "host"}),
 )

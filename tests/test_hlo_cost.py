@@ -87,8 +87,8 @@ def test_memory_bytes_scale_with_shapes():
 
 def test_shape_bytes_narrow_dtypes_exact():
     """int8/pred buffers must be priced at 1 byte/elem, f32 at 4 - the old
-    silent 4-byte default overpriced every narrow buffer 4x (the quant
-    path's planner calibration reads these numbers)."""
+    silent 4-byte default overpriced every narrow buffer 4x (int8 serving
+    buffers are priced by these numbers)."""
     assert hlo_cost._shape_bytes("s8", "16,32") == 16 * 32
     assert hlo_cost._shape_bytes("u8", "8") == 8
     assert hlo_cost._shape_bytes("pred", "64") == 64

@@ -43,6 +43,7 @@ RETIREMENT_MODES = (
                 "forget": 0.9}),
     ("window", {"refresh_mode": "incremental", "retirement": "window",
                 "retire_window": 6}),
+    ("adaptive", {"refresh_mode": "incremental", "retirement": "adaptive"}),
 )
 
 
@@ -148,6 +149,54 @@ def test_pipelined_serving_is_bitwise_the_synchronous_path(depth, mode, kw):
 
 
 # ---------------------------------------------------------------------------
+# One pool step program per step
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("kw", [
+    {}, {"pipeline_depth": 2}, {"quantize": "int8"},
+    {"refresh_mode": "incremental", "retirement": "adaptive"},
+], ids=["sync", "pipelined", "int8", "adaptive"])
+def test_one_pool_step_program_per_step(monkeypatch, kw):
+    """Each ``step()`` dispatches exactly one step program, the donated
+    pool step, refresh rounds included: the host-staged step and its
+    separate refresh programs are never called.  The count goes through
+    the module-level name the server looks up at call time."""
+    calls = []
+    real = ss._stream_step_pool_donated
+
+    def counted(*a, **k):
+        calls.append(k["window"])
+        return real(*a, **k)
+
+    def never(*a, **k):
+        raise AssertionError("a second step program was dispatched")
+
+    monkeypatch.setattr(ss, "_stream_step_pool_donated", counted)
+    for name in ("_stream_step_pool", "_stream_step", "_stream_step_donated",
+                 "_stream_refresh_rows", "_stream_refresh_rows_donated",
+                 "_stream_refresh_factor_rows",
+                 "_stream_refresh_factor_rows_donated"):
+        monkeypatch.setattr(ss, name, never)
+    srv = StreamServer(CFG, t_max=16, max_streams=3, window=2,
+                       phase_steps=2, refresh_every=3, **kw)
+    for s in _episode_streams():
+        srv.submit(s)
+    steps = 0
+    while srv.sched.active():
+        before = len(calls)
+        srv.step()
+        steps += 1
+        assert len(calls) == before + 1
+    srv.drain()
+    assert len(calls) == steps == srv.global_step
+    # the episode crossed refresh rounds, each folded into its step
+    assert srv.global_step > srv.refresh_every
+    for r in srv.completed:
+        assert len(r.preds) == r.n_samples
+
+
+# ---------------------------------------------------------------------------
 # Donation safety
 # ---------------------------------------------------------------------------
 
@@ -227,8 +276,8 @@ class _CheckedServer(StreamServer):
 
 @pytest.mark.parametrize("kind,kw", [
     ("device", {}), ("host", {"staging": "host"}),
-    ("blocked", {"step_block": 2}),
-], ids=["device", "host", "blocked"])
+    ("int8", {"quantize": "int8"}),
+], ids=["device", "host", "int8"])
 @pytest.mark.parametrize("k", [1, 3, 8])
 def test_batched_snapshots_are_bitwise_the_single_slot(k, kind, kw):
     """K streams that complete in the same step retire through one
@@ -396,14 +445,17 @@ def test_pool_grows_for_longer_streams_submitted_later():
     assert preds_d[0] == preds_h[0]
 
 
-def test_fused_infer_slots_dispatch_serves_through_the_pool():
+@pytest.mark.parametrize("mode,kw", RETIREMENT_MODES,
+                         ids=[m for m, _ in RETIREMENT_MODES])
+def test_fused_infer_slots_dispatch_serves_through_the_pool(mode, kw):
     """The slot-axis fused-infer dispatch (`ops.streaming_logits_slots`,
     the TPU latency path exercised here through its XLA ref) serves the
-    device-staged episode end to end and agrees with the shared-forward
-    inference on (nearly) every sample - the two compute the same math
-    through different op orders, so borderline argmaxes may flip."""
-    preds_f, srv = _serve(fused_infer=True)
-    preds_s, _ = _serve(fused_infer=False)
+    device-staged episode end to end in every retirement mode and agrees
+    with the shared-forward inference on (nearly) every sample - the two
+    compute the same math through different op orders, so borderline
+    argmaxes may flip."""
+    preds_f, srv = _serve(fused_infer=True, **kw)
+    preds_s, _ = _serve(fused_infer=False, **kw)
     assert sorted(preds_f) == sorted(preds_s)
     total = agree = 0
     for rid in preds_f:

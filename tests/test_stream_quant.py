@@ -1,15 +1,13 @@
-"""Quantized int8 serving + step blocking (PR 7): equivalence battery.
+"""Quantized int8 serving: equivalence battery.
 
 The contracts under test (see runtime/stream_server.py module docstring):
 
   * the fp32 serving path is BITWISE the PR-6 path: predictions and final
     model state of a full multi-admission episode reproduce the committed
-    golden fixture in every retirement mode (``quantize='none'`` and
-    ``step_block=1`` compile the exact pre-PR-7 program);
-  * step blocking (``step_block=T``) serves the ``step_block=1`` episode
-    exactly - same predictions, same model state - across retirement
-    modes, pipeline depths and the quantized path (the block clamp keeps
-    the admission schedule identical);
+    golden fixture in every retirement mode (``quantize='none'`` compiles
+    the exact pre-PR-7 program);
+  * the int8 episode pipelined at depth 1 or 2 is bit for bit the
+    synchronous int8 episode, in every retirement mode;
   * ``quantize='int8'`` changes ONLY the served logits: training,
     statistics and refreshes are bit-for-bit the fp32 episode, slots arm
     at their first ridge-refresh boundary, and the argmax agreement with
@@ -41,6 +39,7 @@ RETIREMENT_MODES = (
                 "forget": 0.9}),
     ("window", {"refresh_mode": "incremental", "retirement": "window",
                 "retire_window": 6}),
+    ("adaptive", {"refresh_mode": "incremental", "retirement": "adaptive"}),
 )
 
 GOLDEN = "tests/golden/stream_fp32_golden.npz"
@@ -142,7 +141,7 @@ def golden():
 @pytest.mark.parametrize("mode,kw", GOLDEN_MODES,
                          ids=[m for m, _ in GOLDEN_MODES])
 def test_fp32_serving_is_bitwise_the_pr6_golden(golden, mode, kw):
-    """The default-path (quantize='none', step_block=1) episode reproduces
+    """The default-path (quantize='none') episode reproduces
     the pre-PR-7 fixture bit for bit: predictions AND every PR-6 model
     state leaf.  This is the regression gate for 'the fp32 path must stay
     bitwise identical'."""
@@ -159,47 +158,28 @@ def test_fp32_serving_is_bitwise_the_pr6_golden(golden, mode, kw):
 
 
 # ---------------------------------------------------------------------------
-# Step blocking: step_block=T == step_block=1, exactly
+# int8 pipelining: depth D == depth 0, bit for bit
 # ---------------------------------------------------------------------------
 
 
 @pytest.mark.parametrize("mode,kw", RETIREMENT_MODES,
                          ids=[m for m, _ in RETIREMENT_MODES])
-@pytest.mark.parametrize("block", [2, 4])
-def test_step_blocking_serves_the_unblocked_episode(block, mode, kw):
-    """A blocked episode produces the step_block=1 predictions exactly and
-    the same model state (cross-program tolerance on the diagnostic only):
-    the block clamp pins admissions and refreshes to the unblocked
-    schedule, and each sub-step is the same fused pool step."""
-    preds_1, srv_1 = _serve(**kw)
-    preds_b, srv_b = _serve(step_block=block, **kw)
-    assert preds_1 == preds_b
-    _assert_states_equal_cross_program(srv_1.states, srv_b.states)
-    assert srv_1.global_step == srv_b.global_step
-    for a, b in zip(sorted(srv_1.completed, key=lambda r: r.rid),
-                    sorted(srv_b.completed, key=lambda r: r.rid)):
+@pytest.mark.parametrize("depth", [1, 2])
+def test_int8_pipelined_is_bitwise_synchronous(depth, mode, kw):
+    """The quantized episode pipelined at depth 1/2 serves the synchronous
+    quantized episode exactly: same predictions, same model state (the
+    quantization leaves included) and the same retired models - the lag-D
+    ring defers only bookkeeping, and the scale fold rides the same pool
+    step program."""
+    preds_0, srv_0 = _serve(quantize="int8", **kw)
+    preds_d, srv_d = _serve(quantize="int8", pipeline_depth=depth, **kw)
+    assert preds_0 == preds_d
+    _assert_states_bitwise_equal(srv_0.states, srv_d.states)
+    assert srv_0.global_step == srv_d.global_step
+    for a, b in zip(sorted(srv_0.completed, key=lambda r: r.rid),
+                    sorted(srv_d.completed, key=lambda r: r.rid)):
         assert a.correct == b.correct
-        _assert_states_equal_cross_program(a.final_state, b.final_state)
-
-
-def test_step_blocking_composes_with_pipelining_and_quantization():
-    """step_block x pipeline_depth x quantize all compose: the blocked
-    pipelined quantized episode equals the unblocked quantized one."""
-    preds_q, srv_q = _serve(quantize="int8")
-    preds_c, srv_c = _serve(quantize="int8", step_block=3, pipeline_depth=2)
-    assert preds_q == preds_c
-    _assert_states_equal_cross_program(srv_q.states, srv_c.states)
-
-
-def test_step_blocking_dispatches_fewer_programs():
-    """The point of blocking: a blocked episode runs fewer host dispatch
-    rounds (step() calls) while serving every sample."""
-    _, srv_1 = _serve()
-    _, srv_b = _serve(step_block=4)
-    assert len(srv_b.step_times_s) < len(srv_1.step_times_s)
-    assert srv_1.global_step == srv_b.global_step
-    for r in srv_b.completed:
-        assert len(r.preds) == r.n_samples
+        _assert_states_bitwise_equal(a.final_state, b.final_state)
 
 
 # ---------------------------------------------------------------------------
@@ -369,7 +349,7 @@ def test_q8_serving_accepts_zero_streams_end_to_end():
 def test_bf16_inputs_feed_the_int8_path():
     """A bf16 config serves through quantize='int8' (the wrapper upcasts
     the staged window to f32 for the integer kernel; scales stay f32
-    bookkeeping), NaN-free, with the blocked path agreeing exactly."""
+    bookkeeping), NaN-free, with the pipelined path agreeing exactly."""
     cfg = dataclasses.replace(CFG, dtype=jnp.bfloat16)
     streams = lambda: [_make_stream(0, 6, seed=3), _make_stream(1, 6, seed=4)]
     preds_q, srv = _serve(streams(), cfg=cfg, quantize="int8",
@@ -379,9 +359,9 @@ def test_bf16_inputs_feed_the_int8_path():
     assert srv.states.quant.x_absmax.dtype == jnp.float32
     for r in srv.completed:
         assert len(r.preds) == r.n_samples
-    preds_b, _ = _serve(streams(), cfg=cfg, quantize="int8",
-                        refresh_mode="incremental", step_block=2)
-    assert preds_q == preds_b
+    preds_p, _ = _serve(streams(), cfg=cfg, quantize="int8",
+                        refresh_mode="incremental", pipeline_depth=2)
+    assert preds_q == preds_p
 
 
 # ---------------------------------------------------------------------------
@@ -423,10 +403,6 @@ def test_invalid_knob_combinations_fail_loudly():
         mk(quantize="int4")
     with pytest.raises(ValueError, match="staging='device'"):
         mk(quantize="int8", staging="host")
-    with pytest.raises(ValueError, match="step_block"):
-        mk(step_block=0)
-    with pytest.raises(ValueError, match="staging='device'"):
-        mk(step_block=2, staging="host")
 
 
 def test_fold_quant_rows_scatter_contract():

@@ -194,19 +194,6 @@ def test_sharded_quantized_episode_is_bitwise_single_device(devices):
 
 
 @needs_devices
-def test_sharded_blocked_quantized_parity():
-    """step_block (PR 7) composes with sharding and quantization: the
-    8-device blocked quantized episode matches the single-device blocked
-    quantized one, and both serve the unblocked quantized
-    predictions exactly (the block clamp pins the schedule)."""
-    preds_u, _ = _serve(1, quantize="int8")
-    preds_1, srv_1 = _serve(1, quantize="int8", step_block=3)
-    preds_8, srv_8 = _serve(8, quantize="int8", step_block=3)
-    assert preds_u == preds_1 == preds_8
-    assert_parity(srv_1.states, srv_8.states)
-
-
-@needs_devices
 def test_sharded_step_program_has_no_collective():
     """The device-local invariant in the compiled program itself: the
     8-device step's optimized HLO holds no cross-device collective."""

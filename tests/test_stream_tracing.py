@@ -22,12 +22,16 @@ CFG = DFRConfig(n_in=3, n_classes=4, n_nodes=4, nonlinearity="tanh")
 T_MAX, SLOTS, WINDOW, PHASE = 7, 4, 2, 2
 LENGTHS = (5, 9, 3, 12, 7, 6, 4)        # samples per stream
 KINDS = {
+    # device staging at pipeline_depth=0: what the fleet cell runs
+    "sync": dict(),
     # device staging, predictions drained one step late
     "pipelined": dict(pipeline_depth=1),
     # the host-staged batch build and its separate refresh dispatch
     "host": dict(staging="host"),
-    # two steps per dispatch (lax.scan over the pool step)
-    "blocked": dict(step_block=2),
+    # int8 serving: the scale fold rides the refresh branch
+    "int8": dict(quantize="int8"),
+    # the in-step drift detector over live factors
+    "adaptive": dict(retirement="adaptive", refresh_mode="incremental"),
 }
 SCOPES = ("stream.admit_reset", "stream.serve", "stream.kernel",
           "stream.live_select", "stream.factor_fold", "stream.gather",
@@ -194,12 +198,9 @@ def test_step_counters(recorded, kind):
             1 if retires[k] else 0)
         assert st["live"] == len(snaps[k][0]) + st["retired"]
         assert 0 < st["real_timesteps"] <= st["slot_timesteps"]
-        assert st["slot_timesteps"] % (SLOTS * WINDOW * T_MAX) == 0
-    # the global step numbers run on, a block advancing several
-    blocks = [st["slot_timesteps"] // (SLOTS * WINDOW * T_MAX)
-              for st in stats]
-    assert [st["step"] for st in stats] == list(np.cumsum(blocks))
-    assert max(blocks) == (2 if kind == "blocked" else 1)
+        assert st["slot_timesteps"] == SLOTS * WINDOW * T_MAX
+    # the global step numbers run on, one a step
+    assert [st["step"] for st in stats] == list(range(1, len(stats) + 1))
     # every sample is served once: the real timesteps are its lengths
     assert sum(st["real_timesteps"] for st in stats) == sum(
         int(r.length.sum()) for r in done.values())
@@ -234,7 +235,7 @@ def test_snapshot_span_batches_the_steps_retirements(recorded, kind):
     assert min(rows) == 1 and max(rows) > 1
 
 
-@pytest.mark.parametrize("kind", ["pipelined", "host"])
+@pytest.mark.parametrize("kind", sorted(KINDS))
 def test_refresh_eligibility_matches_the_device(recorded, kind):
     """On each refresh step, the eligible rows the host counts are those
     the program refreshes: live in the step, at least ``phase_steps``
