@@ -472,8 +472,9 @@ def _timestep_stats(members: int, lengths: List[Array],
     lengths, padded to ``t_max`` steps, each batch one call of the
     training kernel: member-samples, the real time steps, the time steps
     the arrays hold and those the kernel runs (its Pallas layout, after
-    the length sort and the dead-chunk skip).  Read on the host: a device
-    op here would compile inside a traced window."""
+    the length sort and the dead-chunk skip), and the kernel's accumulator
+    folds (one per live block-chunk).  Read on the host: a device op here
+    would compile inside a traced window."""
     lens = [np.asarray(x) for x in lengths]
     return {
         "samples": members * sum(x.size for x in lens),
@@ -482,6 +483,8 @@ def _timestep_stats(members: int, lengths: List[Array],
             x.size * t for x, t in zip(lens, t_max)),
         "kernel_timesteps": members * sum(
             kops.train_kernel_timesteps(x, t) for x, t in zip(lens, t_max)),
+        "kernel_folds": members * sum(
+            kops.train_kernel_folds(x, t) for x, t in zip(lens, t_max)),
     }
 
 

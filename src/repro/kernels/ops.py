@@ -217,8 +217,9 @@ def train_forward(
     that row, so unsorting the outputs gives bit for bit what the unsorted
     batch gives.  Under ``vmap`` over members with shared inputs (the
     population evaluation), the sort and the gather of ``j_seq`` are not
-    batched: they run once per call.  ``train_kernel_timesteps`` counts
-    the time steps the kernel runs.
+    batched: they run once per call.  ``train_kernel_timesteps`` and
+    ``train_kernel_folds`` count the time steps and the accumulator folds
+    the kernel runs.
     """
     backend = _auto_backend(backend)
     nx = j_seq.shape[-1]
@@ -267,19 +268,36 @@ def _train_layout(b: int, t: int, block_b: int, chunk_t: Optional[int]):
     return chunk_t, b > block_b and t > chunk_t
 
 
-def train_kernel_timesteps(lengths, t: int, *, block_b: int = 8,
-                           chunk_t: Optional[int] = None) -> int:
-    """Time steps the training kernel runs for one ``train_forward`` call
-    on a (B, ``t``) batch with these lengths, counted on the host: per
-    batch block, its live chunks x ``chunk_t`` x ``block_b``, after the
-    wrapper's length sort and the kernel's chunk skip."""
+def _train_live_chunks(lengths, t: int, block_b: int,
+                       chunk_t: Optional[int]):
+    """(live block-chunks, chunk_t) of one ``train_forward`` call on a
+    (B, ``t``) batch with these lengths: per batch block, the time chunks
+    up to its longest row, after the wrapper's length sort."""
     lens = np.clip(np.asarray(lengths, np.int64).reshape(-1), 0, t)
     chunk_t, sort = _train_layout(lens.size, t, block_b, chunk_t)
     if sort:
         lens = np.sort(lens)[::-1]
     lens = np.pad(lens, (0, (-lens.size) % block_b))
     n_live = -(-lens.reshape(-1, block_b).max(axis=1) // chunk_t)
-    return int(n_live.sum()) * chunk_t * block_b
+    return int(n_live.sum()), chunk_t
+
+
+def train_kernel_timesteps(lengths, t: int, *, block_b: int = 8,
+                           chunk_t: Optional[int] = None) -> int:
+    """Time steps the training kernel runs for one ``train_forward`` call
+    on a (B, ``t``) batch with these lengths, counted on the host: per
+    batch block, its live chunks x ``chunk_t`` x ``block_b``, after the
+    wrapper's length sort and the kernel's chunk skip."""
+    chunks, chunk_t = _train_live_chunks(lengths, t, block_b, chunk_t)
+    return chunks * chunk_t * block_b
+
+
+def train_kernel_folds(lengths, t: int, *, block_b: int = 8,
+                       chunk_t: Optional[int] = None) -> int:
+    """Accumulator folds the training kernel runs for the same call: one
+    per live block-chunk (each folds its ``block_b`` samples' chunk
+    stacks into their accumulators)."""
+    return _train_live_chunks(lengths, t, block_b, chunk_t)[0]
 
 
 # ---------------------------------------------------------------------------

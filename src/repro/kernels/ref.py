@@ -12,6 +12,7 @@ import jax.numpy as jnp
 
 from repro.core import dprr as core_dprr
 from repro.core import reservoir as core_res
+from repro.core.types import DOT_PRECISION
 
 
 def dprr_ref(x: jax.Array, length: jax.Array, n_nodes: int) -> jax.Array:
@@ -181,21 +182,28 @@ def train_forward_ref(
     p: jax.Array,
     n_nodes: int,
     f: Callable[[jax.Array], jax.Array] = lambda z: z,
+    *,
+    chunk_t: int,
 ):
     """Oracle of kernels.train.train_forward_pallas on padded shapes.
 
-    Mirrors the kernel's per-step op sequence exactly (same dots on the
-    same padded operands, same masking, same boundary latch order), so the
-    interpret-mode kernel agrees with it bit for bit.  Returns
+    Mirrors the kernel's op sequence exactly (same dots on the same padded
+    operands, same masking, same boundary latch order), so the
+    interpret-mode kernel agrees with it bit for bit: a per-step scan
+    yields the state, the latches and each step's DPRR pair (x(k) masked,
+    [x(k-1), 1]); the accumulator then folds each ``chunk_t``-step chunk
+    with one contraction per sample over the chunk axis, chunk after
+    chunk, as the kernel does.  A dead chunk, which the kernel skips,
+    holds only zero rows x(k) here and adds exactly zero.  Returns
     ``(acc, x_last, x_prev, j_last)`` in the kernel's padded layout.
     """
-    t_pad, n_pad = j_seq.shape[1], j_seq.shape[2]
+    b, t_pad, n_pad = j_seq.shape
     Lt = L.T
     col = jnp.arange(n_pad)[None, :]
 
     def one(jb, length):
         def step(carry, inp):
-            x_prev, acc, x_bnd, j_bnd = carry
+            x_prev, x_bnd, j_bnd = carry
             j_k, k = inp
             a = p.astype(jnp.float32) * f(j_k + x_prev)
             x_k = jax.lax.dot(
@@ -210,22 +218,34 @@ def train_forward_ref(
             x0_aug = jnp.where(
                 col < n_nodes, x_prev, jnp.where(col == n_nodes, 1.0, 0.0)
             )
-            acc = acc + jax.lax.dot_general(
-                x1m, x0_aug,
-                dimension_numbers=(((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            return (x_k, acc, x_bnd, j_bnd), None
+            return (x_k, x_bnd, j_bnd), (x1m[0], x0_aug[0])
 
         z_row = jnp.zeros((1, n_pad), jnp.float32)
-        carry0 = (z_row, jnp.zeros((n_pad, n_pad), jnp.float32), z_row, z_row)
-        (x_last, acc, x_bnd, j_bnd), _ = jax.lax.scan(
-            step, carry0,
+        (x_last, x_bnd, j_bnd), pairs = jax.lax.scan(
+            step, (z_row, z_row, z_row),
             (jb[:, None, :], jnp.arange(t_pad, dtype=jnp.int32)),
         )
-        return acc, x_last[0], x_bnd[0], j_bnd[0]
+        return pairs, x_last[0], x_bnd[0], j_bnd[0]
 
-    return jax.vmap(one)(j_seq, lengths.astype(jnp.int32))
+    (x1s, x0s), x_last, x_prev, j_last = jax.vmap(one)(
+        j_seq, lengths.astype(jnp.int32))
+    stacks = (x1s.reshape(b, t_pad // chunk_t, chunk_t, n_pad),
+              x0s.reshape(b, t_pad // chunk_t, chunk_t, n_pad))
+
+    def fold(acc, chunk):
+        x1, x0 = chunk
+        return acc + jax.lax.dot_general(
+            x1, x0, dimension_numbers=(((0,), (0,)), ((), ())),
+            precision=DOT_PRECISION, preferred_element_type=jnp.float32,
+        ), None
+
+    # one sample at a time (lax.map, not vmap): the unbatched dot the
+    # kernel runs per sample
+    acc = jax.lax.map(
+        lambda c: jax.lax.scan(
+            fold, jnp.zeros((n_pad, n_pad), jnp.float32), c)[0],
+        stacks)
+    return acc, x_last, x_prev, j_last
 
 
 def reservoir_ref(

@@ -28,27 +28,37 @@ accumulator and O(Nx) for the state/boundary rows, independent of T —
 mirroring the FPGA dataflow where the DPRR MACs are wired directly to the
 reservoir ring and only the two boundary states are latched for training.
 
+Accumulator fold: each time step stores its live-masked row x(k) and its
+augmented row [x(k-1), 1] into two VMEM stacks of one time chunk
+(chunk_t * block_b, n_pad), step-major; after the chunk's time loop, each
+sample's accumulator takes one MXU contraction over the chunk axis,
+``acc[b] += X1_b^T . X0_b``, its rows read at stride block_b.  So the
+accumulator costs one (chunk_t, n_pad)^T x (chunk_t, n_pad) matmul per
+sample and chunk instead of a (block_b, n_pad, n_pad) vector outer-product
+update every step; the stacks are bounded by the chunk, not by T.
+
 Boundary capture: step k = length-1 is recognized inside the time loop
 (``k_global == length - 1``) and latches (x_prev, j_k) into VMEM scratch
 rows before the state update, so ``x_prev`` is exactly the ``forward()``
 gather ``x[length-2]`` (zero when length == 1, because the latched value is
 then the initial state).  Dead steps (k >= length) freeze the state and
-contribute zero to the accumulator, matching ``compute_dprr``'s row
-masking bit for bit.
+stack a zero row x(k), so they add nothing to the accumulator, matching
+``compute_dprr``'s row masking.
 
 Grid: (batch_blocks, time_chunks), time minor/sequential so the scratch
 carries across chunks (re-initialized at chunk 0 of every batch block).
 Dead chunks are skipped: a scalar-prefetch operand gives each batch block
-its live chunk count ceil(max(lengths) / chunk_t), the time loop runs only
-in chunks below it, and the input index of a dead chunk is clamped to the
-block's last live one, so no new DMA starts.  Every step of a skipped chunk
-has k >= every length in its block: it would freeze the state, add exactly
-zero to the accumulator and never latch the boundary, so the outputs are
-bit for bit those of the full grid (for finite states).  The init at chunk
-0 and the emit at the last chunk stay outside the gate.
-The ring-mix dot runs at ``Precision.HIGHEST``: the chip's default f32
-matmul rounds its operands to bf16, which the recurrence would carry
-through every step.  Same ring-padding contract as the other kernels
+its live chunk count ceil(max(lengths) / chunk_t), the time loop and the
+fold run only in chunks below it, and the input index of a dead chunk is
+clamped to the block's last live one, so no new DMA starts.  Every step of
+a skipped chunk has k >= every length in its block: it would freeze the
+state, stack only zero rows x(k) (a fold that adds exactly zero) and never
+latch the boundary, so the outputs are bit for bit those of the full grid
+(for finite states).  The init at chunk 0 and the emit at the last chunk
+stay outside the gate.
+The ring-mix dot and the fold run at ``Precision.HIGHEST``: the chip's
+default f32 matmul rounds its operands to bf16, which the recurrence would
+carry through every step.  Same ring-padding contract as the other kernels
 (``ops._ring_padded``):
 L/qpow are built for the padded node count with the true last node
 mirrored into the last padded lane so the in-kernel ring wrap
@@ -93,6 +103,8 @@ def _train_forward_kernel(
     acc,         # VMEM scratch (block_b, n_pad, n_pad) DPRR accumulators
     bnd_x,       # VMEM scratch (block_b, n_pad) boundary latch x(T-1)
     bnd_j,       # VMEM scratch (block_b, n_pad) boundary latch j(T)
+    x1s,         # VMEM scratch (chunk_t * block_b, n_pad) rows x(k), masked
+    x0s,         # VMEM scratch (chunk_t * block_b, n_pad) rows [x(k-1), 1]
     *,
     f: Callable[[jax.Array], jax.Array],
     chunk_t: int,
@@ -100,7 +112,7 @@ def _train_forward_kernel(
 ):
     bb = pl.program_id(0)
     tc = pl.program_id(1)
-    n_pad = state.shape[-1]
+    block_b, n_pad = state.shape
 
     @pl.when(tc == 0)
     def _init():
@@ -114,6 +126,7 @@ def _train_forward_kernel(
     qpow = qpow_ref[...]
     lens = len_ref[...]                           # (block_b, 1)
     col = jax.lax.broadcasted_iota(jnp.int32, (1, n_pad), 1)
+    ones_col = jnp.where(col == n_nodes, 1.0, 0.0)
 
     def step(t, _):
         x_prev = state[...]
@@ -130,14 +143,12 @@ def _train_forward_kernel(
         bnd_j[...] = jnp.where(is_bnd, j_k, bnd_j[...])
         live = k_global < lens
         x_k = jnp.where(live, x_k, x_prev)        # freeze past valid length
-        # DPRR contribution of step k: x(k) . [x(k-1), 1]^T per sample,
-        # masked to the true nodes; a frozen (dead) step contributes
-        # exactly zero, matching compute_dprr's row masking.
-        x1m = jnp.where((col < n_nodes) & live, x_k, 0.0)
-        x0_aug = jnp.where(
-            col < n_nodes, x_prev, jnp.where(col == n_nodes, 1.0, 0.0)
-        )
-        acc[...] += x1m[:, :, None] * x0_aug[:, None, :]
+        # the DPRR pair of step k, x(k) and [x(k-1), 1], masked to the true
+        # nodes: a frozen (dead) step stacks a zero row x(k), so it adds
+        # exactly zero in the fold, matching compute_dprr's row masking
+        rows = pl.ds(pl.multiple_of(t * block_b, block_b), block_b)
+        x1s[rows, :] = jnp.where((col < n_nodes) & live, x_k, 0.0)
+        x0s[rows, :] = jnp.where(col < n_nodes, x_prev, ones_col)
         state[...] = x_k
         return 0
 
@@ -146,6 +157,15 @@ def _train_forward_kernel(
     @pl.when(tc < nlive_ref[bb])
     def _run():
         jax.lax.fori_loop(0, chunk_t, step, 0)
+        # fold the chunk: acc[i] += X1_i^T . X0_i over the chunk's steps,
+        # sample i's rows at stride block_b in the step-major stacks
+        for i in range(block_b):
+            rows = pl.ds(i, chunk_t, stride=block_b)
+            acc[i] += jax.lax.dot_general(
+                x1s[rows, :], x0s[rows, :],
+                dimension_numbers=(((0,), (0,)), ((), ())),
+                precision=DOT_PRECISION, preferred_element_type=jnp.float32,
+            )
 
     @pl.when(tc == pl.num_programs(1) - 1)
     def _emit():
@@ -217,6 +237,8 @@ def train_forward_pallas(
             pltpu.VMEM((block_b, n_pad, n_pad), jnp.float32),
             pltpu.VMEM((block_b, n_pad), jnp.float32),
             pltpu.VMEM((block_b, n_pad), jnp.float32),
+            pltpu.VMEM((chunk_t * block_b, n_pad), jnp.float32),
+            pltpu.VMEM((chunk_t * block_b, n_pad), jnp.float32),
         ],
     )
     return pl.pallas_call(

@@ -89,21 +89,24 @@ def test_streaming_logits_slots_compiles(spec):
     )
 
 
-@pytest.mark.parametrize("members", [None, 8, 64])
-def test_train_forward_compiles(spec, members):
+@pytest.mark.parametrize("members, b, t", [
+    (None, 64, 128), (8, 256, T_MAX), (64, 808, 994), (64, 8, 994),
+], ids=["None", "8", "64", "64-refine"])
+def test_train_forward_compiles(spec, members, b, t):
     """The fused training forward, alone (B=64, T=128), vmapped over
-    population candidates at the refinement shape (B=256, T=93), and at
-    the NET evaluation's (64 members, B=808, T=994: the length sort and
-    the scalar-prefetched live chunk counts), whose temporaries stay
-    within the evaluation's 4.26 GB plus the sorted copy of the input."""
+    population candidates at B=256, T=93, at the NET evaluation's shape
+    (64 members, B=808, T=994: the length sort and the scalar-prefetched
+    live chunk counts), whose temporaries stay within the evaluation's
+    4.26 GB plus the sorted copy of the input (the chunk stacks of the
+    accumulator fold are VMEM scratch), and at the NET refinement's (64
+    members, one minibatch of 8, T=994)."""
     if members is None:
         _assert_kernel(
             lambda j, ln, p, q: ops.train_forward(j, ln, p, q, NX,
                                                   backend="tpu"),
-            spec((64, 128, NX)), spec((64,), jnp.int32), spec(()), spec(()),
+            spec((b, t, NX)), spec((b,), jnp.int32), spec(()), spec(()),
         )
         return
-    b, t = (256, T_MAX) if members == 8 else (808, 994)
     fn = jax.vmap(lambda j, ln, p, q: ops.train_forward(j, ln, p, q, NX,
                                                         backend="tpu"),
                   in_axes=(None, None, 0, 0))

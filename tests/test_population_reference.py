@@ -241,6 +241,23 @@ def test_search_spans_count_kernel_timesteps(traced):
     assert {"search.evaluate", "search.refine"} <= seen
 
 
+def test_search_spans_count_kernel_folds(traced):
+    """By hand: one accumulator fold per live 8-row block and 16-step
+    chunk, per member: the evaluation's 3 + 1 blocks of one chunk each,
+    the refinement's 4 minibatches one block each."""
+    spans, *_ = traced
+    k = DIVS ** 2
+    seen = set()
+    for name, _s, _e, st in spans:
+        if name == "search.evaluate":
+            assert st["kernel_folds"] == k * (3 + 1)
+            assert st["kernel_timesteps"] == st["kernel_folds"] * 16 * 8
+        if name == "search.refine":
+            assert st["kernel_folds"] == k * 4
+        seen.add(name)
+    assert {"search.evaluate", "search.refine"} <= seen
+
+
 def test_kernel_timesteps_on_net_lengths():
     """NET's 803 + 534 lengths spread over 50-994 (T 994: chunks of 128,
     1,024 steps): sorted by length, longest first, the 101 + 67 blocks
@@ -263,6 +280,11 @@ def test_kernel_timesteps_on_net_lengths():
     assert ops.train_kernel_timesteps([0] * 8, 994) == 0
     assert ops.train_kernel_timesteps([5, 129, 0], 994) == 2 * 128 * 8
     assert ops.train_kernel_timesteps([3, 0] * 9, 12) == 3 * 16 * 8
+    # one accumulator fold per live block-chunk
+    assert ops.train_kernel_folds(tr, 994) == 462
+    assert ops.train_kernel_folds(ev, 994) == 307
+    assert ops.train_kernel_folds([5, 129, 0], 994) == 2
+    assert ops.train_kernel_folds([0] * 8, 994) == 0
 
 
 def test_spans_compute_nothing_unrecorded(monkeypatch):

@@ -7,7 +7,8 @@ Three layers of parity pin the production training path:
   ``grads_truncated`` (autodiff of the stop_gradient objective) - a
   hypothesis battery over shapes, signs of q, ragged lengths and dtypes;
 * the interpret-backend Pallas kernel BITWISE against the ``kernels.ref``
-  oracle (same op order on padded shapes);
+  oracle (same op order on padded shapes), and within float32 rounding
+  against the per-step outer-product accumulation;
 * the call-site contracts: ``online_serve_step(fused=True)``,
   ``refine_population(fused=...)`` and the jit-cache (retrace) regression
   for the identity-cached ``DFRConfig.f()``.
@@ -183,7 +184,8 @@ def test_interpret_kernel_bitwise_matches_ref_oracle(q):
     got = train_forward_pallas(jp, Lp, qp, lens, p, qv, nx, f=f,
                                block_b=block_b, chunk_t=chunk_t,
                                interpret=True)
-    ref = kref.train_forward_ref(jp, Lp, qp, lens, p, nx, f=f)
+    ref = kref.train_forward_ref(jp, Lp, qp, lens, p, nx, f=f,
+                                 chunk_t=chunk_t)
     for g, r, name in zip(got, ref, ("acc", "x_last", "x_prev", "j_last")):
         np.testing.assert_array_equal(np.asarray(g), np.asarray(r),
                                       err_msg=name)
@@ -235,7 +237,8 @@ def test_interpret_kernel_skips_dead_chunks_bitwise(pattern):
                       0, block_b)
     Lp, qp = kops._ring_padded(q, nx, 128)
     lp = kops._pad_to(lengths, 0, block_b)
-    ref = kref.train_forward_ref(jp, Lp, qp, lp, p, nx, f=f)
+    ref = kref.train_forward_ref(jp, Lp, qp, lp, p, nx, f=f,
+                                 chunk_t=chunk_t)
     got = train_forward_pallas(jp, Lp, qp, lp, p, q, nx, f=f,
                                block_b=block_b, chunk_t=chunk_t,
                                interpret=True)
@@ -248,6 +251,66 @@ def test_interpret_kernel_skips_dead_chunks_bitwise(pattern):
     for g, r, name in zip(wrapped, _logical(ref, b, nx),
                           ("r", "x_last", "x_prev", "j_last")):
         np.testing.assert_array_equal(np.asarray(g), r, err_msg=name)
+
+
+def _per_step_forward(jp, Lp, qp, lens, p, nx, f):
+    """The accumulation the kernel's chunk fold replaces: one
+    x(k) . [x(k-1), 1]^T outer product added every step, per sample."""
+    n_pad = jp.shape[-1]
+    col = jnp.arange(n_pad)[None, :]
+
+    def one(jb, length):
+        def step(carry, inp):
+            x_prev, acc, x_bnd, j_bnd = carry
+            j_k, k = inp
+            a = p * f(j_k + x_prev)
+            x_k = jax.lax.dot(a, Lp.T) + x_prev[:, -1:] * qp[None, :]
+            is_bnd = k == length - 1
+            x_bnd = jnp.where(is_bnd, x_prev, x_bnd)
+            j_bnd = jnp.where(is_bnd, j_k, j_bnd)
+            live = k < length
+            x_k = jnp.where(live, x_k, x_prev)
+            x1m = jnp.where((col < nx) & live, x_k, 0.0)
+            x0_aug = jnp.where(col < nx, x_prev,
+                               jnp.where(col == nx, 1.0, 0.0))
+            return (x_k, acc + x1m[0][:, None] * x0_aug[0][None, :],
+                    x_bnd, j_bnd), None
+
+        z = jnp.zeros((1, n_pad), jnp.float32)
+        (x_last, acc, x_bnd, j_bnd), _ = jax.lax.scan(
+            step, (z, jnp.zeros((n_pad, n_pad), jnp.float32), z, z),
+            (jb[:, None, :], jnp.arange(jb.shape[0], dtype=jnp.int32)))
+        return acc, x_last[0], x_bnd[0], j_bnd[0]
+
+    return jax.vmap(one)(jp, lens)
+
+
+@pytest.mark.parametrize("chunk_t", [8, 128])
+def test_interpret_kernel_fold_matches_per_step_accumulation(chunk_t):
+    """The chunk fold against the per-step accumulation, with lengths at
+    the chunk seams (chunk_t - 1, chunk_t, chunk_t + 1), 0, 1 and the full
+    window: the accumulator within float32 rounding of the summation
+    order, the boundary rows bit for bit."""
+    t, nx, block_b = 2 * chunk_t + 5, 5, 8
+    lens = np.asarray([chunk_t - 1, chunk_t, chunk_t + 1, 0, 1, t,
+                       2 * chunk_t, 3, t, chunk_t + 1, 0, chunk_t - 1])
+    b = lens.size
+    j_seq = jax.random.normal(jax.random.PRNGKey(chunk_t), (b, t, nx),
+                              jnp.float32)
+    p, q, f = jnp.float32(0.3), jnp.float32(0.5), jnp.tanh
+    jp = kops._pad_to(kops._pad_to(kops._pad_to(j_seq, 2, 128), 1, chunk_t),
+                      0, block_b)
+    Lp, qp = kops._ring_padded(q, nx, 128)
+    lp = kops._pad_to(jnp.asarray(lens, jnp.int32), 0, block_b)
+    got = train_forward_pallas(jp, Lp, qp, lp, p, q, nx, f=f,
+                               block_b=block_b, chunk_t=chunk_t,
+                               interpret=True)
+    want = _per_step_forward(jp, Lp, qp, lp, p, nx, f)
+    np.testing.assert_allclose(np.asarray(got[0]), np.asarray(want[0]),
+                               rtol=1e-5, atol=1e-6, err_msg="acc")
+    for g, w, name in zip(got[1:], want[1:], ("x_last", "x_prev", "j_last")):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w),
+                                      err_msg=name)
 
 
 def _sort_operands(fn, *args):
